@@ -1,13 +1,1 @@
-"""Phocas reproduction package.
-
-Importing any ``repro.*`` module installs the jax-version compat shims
-(``repro.dist.compat``): the codebase and its tests target the modern jax
-sharding surface (``jax.shard_map``, ``jax.set_mesh``,
-``jax.sharding.AxisType``/``get_abstract_mesh``), back-filled onto the
-pinned 0.4-era jax.  The install is attribute-level and touches no jax
-device state, so import order vs. XLA_FLAGS does not matter.
-"""
-from repro.dist import compat as _compat
-
-_compat.install()
-del _compat
+"""Phocas reproduction package."""

@@ -1,9 +1,8 @@
 """``repro.dist`` — SPMD sharding subsystem (DESIGN.md §3).
 
 Mesh-role derivation and PartitionSpec rules (:mod:`repro.dist.sharding`),
-layout-agnostic collectives for shard_map bodies
-(:mod:`repro.dist.collectives`), and the jax-version compat layer
-(:mod:`repro.dist.compat`, installed at ``repro`` package import).
+and layout-agnostic collectives for shard_map bodies
+(:mod:`repro.dist.collectives`).
 """
 from repro.dist.collectives import (  # noqa: F401
     all_to_all_scatter, axis_size, gather_slices, gather_workers,

@@ -100,7 +100,8 @@ class SyncPS(Topology):
         from repro.compress.pipeline import bytes_per_round
         from repro.compress.spec import make_codec
         from repro.faults.injector import make_injector, resolve_quorum
-        from repro.train.step import make_train_step, shard_params
+        from repro.train.step import (make_train_step, replicate_unsharded,
+                                      shard_params)
 
         m = plan.num_workers
         robust_cfg = plan.robust_cfg
@@ -152,6 +153,9 @@ class SyncPS(Topology):
             if dcfg is not None:
                 from repro.defense.reputation import init_reputation
                 defense_state = init_reputation(m)
+            if plan.mesh is not None:
+                opt_state, defense_state = replicate_unsharded(
+                    (opt_state, defense_state), plan.mesh)
         dense_dim = 0
         resid = None
         if codec is not None:

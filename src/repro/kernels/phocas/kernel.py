@@ -32,29 +32,26 @@ from jax.experimental import pallas as pl
 from repro.core.selection import (nearest_window_sum, sorted_rows,
                                   stable_ranks, trimmed_mean_of_sorted)
 from repro.kernels.common import (DEFAULT_TILE_D, INTERPRET, extract_max,
-                                  extract_min, pad_lanes)
+                                  extract_min, masked_sum, pad_lanes)
 from repro.kernels.trmean.kernel import (COUNTS_LANES, _counts_row,
                                          _lane_mask, _rows_of, use_network)
 
 
 def _trimmed_center(u, *, b: int, m: int):
-    """(total, trimmed-mean center) of an (m, TILE_D) block."""
-    total = jnp.sum(u, axis=0)
-    tm_total = total
+    """Trimmed-mean center of an (m, TILE_D) block."""
     valid = jnp.ones(u.shape, jnp.bool_)
     for _ in range(b):
-        valid, tm_total, _ = extract_min(u, valid, tm_total)
+        valid = extract_min(u, valid)
     for _ in range(b):
-        valid, tm_total, _ = extract_max(u, valid, tm_total)
-    return total, tm_total / (m - 2 * b)
+        valid = extract_max(u, valid)
+    return masked_sum(u, valid) / (m - 2 * b)
 
 
-def _drop_farthest(u, center, total, *, b: int):
-    """Remove the b values farthest from ``center`` from ``total``.
+def _drop_farthest(u, center, *, b: int):
+    """(m, TILE_D) mask of the b values farthest from ``center``.
 
     Ties break on the HIGHEST worker index, matching the stable-argsort
     oracle (which ranks lower indices as "nearer" on equal distance).
-    Returns (kept total, (m, TILE_D) dropped mask).
     """
     dist = jnp.abs(u - center[None])
     iota = jax.lax.broadcasted_iota(jnp.int32, u.shape, 0)
@@ -63,17 +60,15 @@ def _drop_farthest(u, center, total, *, b: int):
         mx = jnp.max(dist, axis=0)
         idx = jnp.max(jnp.where(dist == mx[None], iota, -1), axis=0)
         onehot = iota == idx[None]
-        total = total - jnp.sum(jnp.where(onehot, u, 0.0), axis=0)
         dist = jnp.where(onehot, -jnp.inf, dist)
         dropped = dropped | onehot
-    return total, dropped
+    return dropped
 
 
 def _phocas_kernel(u_ref, o_ref, *, b: int, m: int):
     u = u_ref[...].astype(jnp.float32)              # (m, TILE_D)
-    total, center = _trimmed_center(u, b=b, m=m)
-    keep_total, _ = _drop_farthest(u, center, total, b=b)
-    o_ref[...] = (keep_total / (m - b))[None]
+    dropped = _drop_farthest(u, _trimmed_center(u, b=b, m=m), b=b)
+    o_ref[...] = (masked_sum(u, ~dropped) / (m - b))[None]
 
 
 def _phocas_kernel_net(u_ref, o_ref, *, b: int, m: int):
@@ -96,8 +91,8 @@ def _phocas_counts_kernel(u_ref, o_ref, c_ref, *, b: int, m: int, d: int,
         ranks = stable_ranks([jnp.abs(r - center) for r in rows])
         dropped = jnp.stack([r >= m - b for r in ranks])
     else:
-        total, center = _trimmed_center(u, b=b, m=m)
-        total, dropped = _drop_farthest(u, center, total, b=b)
+        dropped = _drop_farthest(u, _trimmed_center(u, b=b, m=m), b=b)
+        total = masked_sum(u, ~dropped)
     o_ref[...] = (total / (m - b))[None]
     c_ref[...] = _counts_row(dropped, lane_ok, m)
 
@@ -145,10 +140,11 @@ def phocas_counts_pallas(u: jax.Array, b: int, *,
         grid=(nblocks,),
         in_specs=[pl.BlockSpec((m, tile_d), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((1, tile_d), lambda i: (0, i)),
-                   pl.BlockSpec((1, COUNTS_LANES), lambda i: (i, 0))],
+                   pl.BlockSpec((None, 1, COUNTS_LANES),
+                                lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, dp), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, COUNTS_LANES),
+                   jax.ShapeDtypeStruct((nblocks, 1, COUNTS_LANES),
                                         jnp.float32)],
         interpret=interpret,
     )(u)
-    return agg[0, :d], jnp.sum(counts, axis=0)[:m]
+    return agg[0, :d], jnp.sum(counts, axis=(0, 1))[:m]

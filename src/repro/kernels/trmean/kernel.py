@@ -30,7 +30,8 @@ from jax.experimental import pallas as pl
 from repro.core.selection import (network_stages, sorted_rows, stable_ranks,
                                   trimmed_mean_of_sorted)
 from repro.kernels.common import (DEFAULT_TILE_D, INTERPRET, extract_max,
-                                  extract_max_stable, extract_min, pad_lanes)
+                                  extract_max_stable, extract_min, masked_sum,
+                                  pad_lanes)
 
 # Score kernels pack per-worker counts into one 128-lane output row.
 COUNTS_LANES = 128
@@ -60,13 +61,12 @@ def _counts_row(dropped, lane_ok, m: int):
 
 def _trmean_kernel(u_ref, o_ref, *, b: int, m: int):
     u = u_ref[...].astype(jnp.float32)          # (m, TILE_D)
-    total = jnp.sum(u, axis=0)                  # (TILE_D,)
     valid = jnp.ones(u.shape, jnp.bool_)
     for _ in range(b):                          # b static & small: unrolled
-        valid, total, _ = extract_min(u, valid, total)
+        valid = extract_min(u, valid)
     for _ in range(b):
-        valid, total, _ = extract_max(u, valid, total)
-    o_ref[...] = (total / (m - 2 * b))[None]
+        valid = extract_max(u, valid)
+    o_ref[...] = (masked_sum(u, valid) / (m - 2 * b))[None]
 
 
 def _rows_of(u, m: int):
@@ -92,13 +92,12 @@ def _trmean_counts_kernel(u_ref, o_ref, c_ref, *, b: int, m: int, d: int,
         ranks = stable_ranks(rows)
         dropped = jnp.stack([(r < b) | (r >= m - b) for r in ranks])
     else:
-        total = jnp.sum(u, axis=0)
         valid = jnp.ones(u.shape, jnp.bool_)
         for _ in range(b):
-            valid, total, _ = extract_min(u, valid, total)
+            valid = extract_min(u, valid)
         for _ in range(b):
-            valid, total, _ = extract_max_stable(u, valid, total)
-        agg = total / (m - 2 * b)
+            valid = extract_max_stable(u, valid)
+        agg = masked_sum(u, valid) / (m - 2 * b)
         dropped = ~valid
     o_ref[...] = agg[None]
     c_ref[...] = _counts_row(dropped, lane_ok, m)
@@ -147,10 +146,11 @@ def trmean_counts_pallas(u: jax.Array, b: int, *,
         grid=(nblocks,),
         in_specs=[pl.BlockSpec((m, tile_d), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((1, tile_d), lambda i: (0, i)),
-                   pl.BlockSpec((1, COUNTS_LANES), lambda i: (i, 0))],
+                   pl.BlockSpec((None, 1, COUNTS_LANES),
+                                lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((1, dp), jnp.float32),
-                   jax.ShapeDtypeStruct((nblocks, COUNTS_LANES),
+                   jax.ShapeDtypeStruct((nblocks, 1, COUNTS_LANES),
                                         jnp.float32)],
         interpret=interpret,
     )(u)
-    return agg[0, :d], jnp.sum(counts, axis=0)[:m]
+    return agg[0, :d], jnp.sum(counts, axis=(0, 1))[:m]

@@ -175,8 +175,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool, layout: str,
             compiled = lowered.compile()
         rec["compile_s"] = time.time() - t1
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):      # jax<=0.4 returns [dict]
-            ca = ca[0] if ca else {}
         rec["xla_flops_raw"] = float(ca.get("flops", -1.0))
         rec["xla_bytes_raw"] = float(ca.get("bytes accessed", -1.0))
         ma = compiled.memory_analysis()

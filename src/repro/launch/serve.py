@@ -28,6 +28,7 @@ import time
 import jax
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import generate
 
@@ -159,6 +160,7 @@ def main():
                     help="capture a jax.profiler trace of the run into "
                          "this directory (view with TensorBoard)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     model = build_model(cfg)

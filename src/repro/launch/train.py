@@ -24,6 +24,7 @@ from repro.core import AttackConfig, RobustConfig, registry
 from repro.experiment import (ScenarioSpec, DataSpec, ModelSpec, SpecError,
                               available_topologies, run_experiment)
 from repro.faults import FaultSpec, available_fault_kinds
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import OptConfig
 
 
@@ -270,6 +271,7 @@ def main():
     except SpecError as e:
         ap.error(str(e))
 
+    enable_compile_cache()
     obs = None
     if args.metrics or args.profile_dir:
         from repro.obs import ObsConfig

@@ -29,29 +29,17 @@ def dtype_of(cfg) -> jnp.dtype:
     return jnp.dtype(cfg.compute_dtype)
 
 
-def get_abstract_mesh():
-    """Compat shim: ``jax.sharding.get_abstract_mesh`` is absent in the
-    pinned jax 0.4.37 — fall back to the legacy ambient mesh set by
-    ``with mesh:`` / the ``jax.set_mesh`` shim (an empty ``Mesh()`` when no
-    mesh context is active, matching the modern empty AbstractMesh)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from repro.dist.compat import _ambient_mesh
-    return _ambient_mesh()
-
-
 def model_axis_size() -> int:
     """Size of the ambient mesh's 'model' axis (0 when no mesh is active —
     single-device tests / examples)."""
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or am.empty or "model" not in am.axis_names:
         return 0
     return am.shape["model"]
 
 
 def data_axis_size() -> int:
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or am.empty or "data" not in am.axis_names:
         return 0
     return am.shape["data"]
@@ -59,7 +47,7 @@ def data_axis_size() -> int:
 
 def shard_hint(x: jax.Array, spec: tuple) -> jax.Array:
     """with_sharding_constraint when a mesh is active; no-op otherwise."""
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or am.empty:
         return x
     from jax.sharding import PartitionSpec as P
@@ -306,8 +294,8 @@ def _attend_paged(q, k, v, pos, *, cap, scale=None):
     """Decode attention with per-request lengths.  q: (B,1,H,hd); k/v:
     (B,T,Kv,hd) gathered per-request views; pos: (B,) newest position of
     each request.  Same einsum contractions / f32 softmax / NaN guard as
-    :func:`_attend`, so paged and dense decode agree bit-for-bit — the only
-    change is the validity mask going per-request (B,T)."""
+    :func:`_attend`, so paged and dense decode agree up to float32 rounding
+    — the only change is the validity mask going per-request (B,T)."""
     B, Sq, H, hd = q.shape
     T, Kv = k.shape[1], k.shape[2]
     rep = H // Kv

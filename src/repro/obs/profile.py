@@ -6,8 +6,9 @@ compile() → cost_analysis()`` and ``memory_stats()``), but packaged for
 a live run: the Recorder samples FLOPs/bytes once per compiled step
 function and device memory per log interval, so the numbers land next to
 loss/latency in the same JSONL stream instead of in a separate dryrun
-report.  Everything degrades to empty dicts on backends that don't
-implement the introspection APIs — profiling must never fail a run.
+report.  Only the CPU backend, which has no device memory to report, reads
+as empty; on an accelerator a failing profiler or missing memory stats
+raise, so a run never reports a device it did not measure.
 """
 from __future__ import annotations
 
@@ -23,14 +24,7 @@ def compiled_cost(jitted_fn, *args) -> Dict[str, float]:
     XLA's ``cost_analysis()``.  Returns ``{}`` when the backend doesn't
     report costs.
     """
-    try:
-        compiled = jitted_fn.lower(*args).compile()
-        ca = compiled.cost_analysis() or {}
-        # jax<=0.4 returns a one-element list of dicts.
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-    except Exception:
-        return {}
+    ca = jitted_fn.lower(*args).compile().cost_analysis() or {}
     out = {}
     for key, name in (("flops", "flops"), ("bytes accessed", "bytes")):
         v = ca.get(key)
@@ -39,24 +33,21 @@ def compiled_cost(jitted_fn, *args) -> Dict[str, float]:
     return out
 
 
-def device_memory_stats() -> Dict[str, float]:
-    """Live/peak device memory in bytes for device 0, or ``{}`` (the CPU
-    backend typically has no allocator stats)."""
-    try:
-        import jax
-        dev = jax.devices()[0]
-        stats = dev.memory_stats()
-    except Exception:
+def device_memory_stats(device=None) -> Dict[str, float]:
+    """Live/peak memory in bytes of ``device`` (default: the first one).
+
+    ``{}`` on the CPU backend, which has no device memory; any other
+    backend that reports no allocator stats raises."""
+    import jax
+    dev = jax.devices()[0] if device is None else device
+    if dev.platform == "cpu":
         return {}
+    stats = dev.memory_stats()
     if not stats:
-        return {}
-    out = {}
-    for key, name in (("bytes_in_use", "bytes_in_use"),
-                      ("peak_bytes_in_use", "peak_bytes_in_use")):
-        v = stats.get(key)
-        if v is not None:
-            out[name] = float(v)
-    return out
+        raise RuntimeError(f"{dev.platform} device {dev.device_kind!r} "
+                           "reports no memory stats")
+    return {name: float(stats[name])
+            for name in ("bytes_in_use", "peak_bytes_in_use")}
 
 
 @contextlib.contextmanager
@@ -66,26 +57,14 @@ def profile_trace(profile_dir: Optional[str]):
     No-op when ``profile_dir`` is falsy (the default path: launch CLIs
     wrap their whole run in this unconditionally).  Spans opened inside
     the window appear as TraceAnnotation regions in the captured trace
-    (obs/trace.py).  Failure to start the profiler — unsupported backend,
-    unwritable dir — degrades to running unprofiled rather than raising.
+    (obs/trace.py).  A requested trace whose profiler cannot start raises.
     """
     if not profile_dir:
         yield
         return
-    try:
-        import jax
-        jax.profiler.start_trace(profile_dir)
-        started = True
-    except Exception:
-        started = False
-    try:
+    import jax
+    with jax.profiler.trace(profile_dir):
         yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
 
 
 def sample_into(recorder, prefix: str = "device") -> None:

@@ -8,7 +8,8 @@ the host side is this module: a free-list :class:`BlockAllocator` and the
 gathers through.  Exact equivalence with the dense ring cache is a layout
 argument, not an approximation: valid positions land at the same (position ->
 k/v) mapping through the table indirection, masked positions contribute
-exactly zero attention weight (tests/test_serve.py asserts bitwise equality).
+exactly zero attention weight (tests/test_serve.py asserts equal logits up to
+float32 rounding, and equal greedy tokens).
 
 Block 0 is the reserved null/trash block: it is never allocated, inactive
 batch slots keep all-zero table rows that scatter their writes there, and any

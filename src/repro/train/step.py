@@ -209,3 +209,14 @@ def shard_params(params, mesh: Mesh):
     return jax.tree.map(
         lambda x, sp: jax.device_put(x, NamedSharding(mesh, sp)),
         params, specs)
+
+
+def replicate_unsharded(tree, mesh: Mesh):
+    """Device-put the leaves of ``tree`` not yet laid out on ``mesh`` (step
+    counters, reputation vectors) replicated over it.  The step returns
+    them replicated; fed from one device, its first call would compile a
+    program of its own."""
+    rep = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: x if isinstance(x.sharding, NamedSharding)
+        else jax.device_put(x, rep), tree)

@@ -106,6 +106,37 @@ def test_distributed_train_step_on_mesh():
     assert res["last"] < res["first"], res
 
 
+SYNC_PS_ONE_COMPILE = r"""
+import jax, json
+from repro.core import AttackConfig, RobustConfig
+from repro.defense import DefenseConfig
+from repro.experiment import DataSpec, ModelSpec, ScenarioSpec, run_experiment
+from repro.train import step as step_mod
+
+made = []
+def recording(*a, **kw):
+    made.append(real(*a, **kw))
+    return made[-1]
+real, step_mod.make_train_step = step_mod.make_train_step, recording
+spec = ScenarioSpec(
+    name='mesh-one-compile', topology='sync_ps',
+    model=ModelSpec(kind='arch', arch='granite-8b-reduced'),
+    data=DataSpec(kind='tokens', seq_len=16, batch_per_worker=1),
+    robust=RobustConfig(rule='phocas', b=1, layout='sharded'),
+    attack=AttackConfig(name='gaussian', num_byzantine=1),
+    defense=DefenseConfig(), num_workers=4, steps=3, mesh='4x1')
+run_experiment(spec)
+print(json.dumps([f._cache_size() for f in made]))
+"""
+
+
+def test_sync_ps_mesh_step_compiles_once():
+    """The mesh loop feeds its first step the optimizer and defense state
+    laid out as the step returns them, so every step reuses one program."""
+    out = run_sub(SYNC_PS_ONE_COMPILE, devices=4)
+    assert json.loads(out.strip().splitlines()[-1]) == [1]
+
+
 MULTIPOD = r"""
 import os
 import jax, jax.numpy as jnp, numpy as np, json

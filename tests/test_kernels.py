@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops as kops
 from repro.kernels.krum.ref import pairwise_sq_dists_ref
@@ -107,6 +107,21 @@ def test_phocas_kernel_property(m, d, seed):
     if b == 0:
         return
     np.testing.assert_allclose(kops.phocas(u, b), phocas_ref(u, b), atol=1e-4)
+
+
+@pytest.mark.parametrize("rule", ["trmean", "phocas"])
+@pytest.mark.parametrize("counts", [False, True])
+def test_kernel_exact_beside_attack_rows(rule, counts):
+    """A trimmed N(0, 200²) attack row must not leak rounding into the
+    honest 1e-4-scale average: the kernels sum the kept values, they never
+    subtract the dropped ones from a total that passed through the attack."""
+    u = 1e-4 * jax.random.normal(KEY, (6, 4096))
+    u = u.at[0].set(200.0 * jax.random.normal(jax.random.fold_in(KEY, 1),
+                                              (4096,)))
+    ref = {"trmean": trmean_ref, "phocas": phocas_ref}[rule](u, 1)
+    fn = getattr(kops, f"{rule}_with_counts" if counts else rule)
+    got = fn(u, 1)[0] if counts else fn(u, 1)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-10)
 
 
 def test_kernel_with_duplicate_values_ties():

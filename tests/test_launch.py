@@ -39,26 +39,48 @@ def test_trainer_loop_end_to_end(tmp_path):
 
 
 @pytest.mark.slow
-def test_train_cli():
+def test_train_cli(tmp_path):
+    env = dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch",
          "gemma2-2b-reduced", "--steps", "6", "--global-batch", "8",
          "--seq-len", "16", "--workers", "4", "--rule", "phocas", "--b",
          "1", "--attack", "gaussian", "--q", "1"],
-        capture_output=True, text=True, env=ENV, timeout=560, cwd=REPO)
+        capture_output=True, text=True, env=env, timeout=560, cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "[train] done" in out.stdout
 
 
 @pytest.mark.slow
-def test_serve_cli():
+def test_serve_cli(tmp_path):
+    env = dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.serve", "--arch",
          "granite-8b-reduced", "--batch", "2", "--prompt-len", "4",
          "--new-tokens", "4"],
-        capture_output=True, text=True, env=ENV, timeout=560, cwd=REPO)
+        capture_output=True, text=True, env=env, timeout=560, cwd=REPO)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "tok/s" in out.stdout
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The CLIs' compile cache: JAX_COMPILATION_CACHE_DIR wins; otherwise
+    the fixed, git-ignored .jax_cache/ at the checkout root."""
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == os.path.join(REPO, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            REPO, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 def test_hlo_collectives_accounting_multidevice():
