@@ -23,7 +23,6 @@ import json
 import math
 import os
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -41,14 +40,12 @@ from repro.core.selection import trim_family  # noqa: E402
 from repro.data.pipeline import TokenStream, make_worker_batches  # noqa: E402
 from repro.defense import DefenseConfig  # noqa: E402
 from repro.defense.reputation import init_reputation  # noqa: E402
-from repro.defense.telemetry import read_jsonl  # noqa: E402
 from repro.experiment import (DataSpec, ModelSpec, ScenarioSpec,  # noqa: E402
                               resolve)
 from repro.experiment.topology import make_topology  # noqa: E402
 from repro.kernels.phocas.ops import phocas_with_counts  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.obs import ObsConfig, make_recorder  # noqa: E402
 from repro.obs.profile import device_memory_stats  # noqa: E402
 from repro.optim import OptConfig, init_opt_state  # noqa: E402
 from repro.serve import (RobustDecoder, ServeEngine,  # noqa: E402
@@ -132,7 +129,7 @@ def train_config():
         vocab_size=GRANITE.vocab_size // VOCAB_SHARE)
 
 
-def train_plan(*, steps: int, mesh: str, telemetry: str, seed: int,
+def train_plan(*, steps: int, mesh: str, seed: int,
                q: int = TRIM, optimizer: str = "sgd"):
     """The resolved sync_ps plan, its model swapped for the chip-share cut
     of granite-8b and its token stream for one over that vocabulary; ``q``
@@ -148,9 +145,8 @@ def train_plan(*, steps: int, mesh: str, telemetry: str, seed: int,
                             num_byzantine=q),
         defense=DefenseConfig(), opt=OptConfig(name=optimizer, lr=0.1),
         num_workers=WORKERS, steps=steps, seed=seed, mesh=mesh,
-        telemetry_path=telemetry)
-    plan = resolve(spec, obs=ObsConfig(enabled=True, trace=True,
-                                       profile_cost=False))
+        log_every=1)
+    plan = resolve(spec)
     cfg = train_config()
     stream = TokenStream(vocab_size=cfg.vocab_size, seq_len=SEQ_LEN,
                          global_batch=WORKERS * SEQS_PER_WORKER, seed=seed)
@@ -176,9 +172,13 @@ def build_step(plan):
                            defense_cfg=plan.defense_cfg)
 
 
-def span_ms(telemetry: str, name: str):
-    return [r["ms"] for r in read_jsonl(telemetry)
-            if r.get("kind") == "span" and r.get("name") == name]
+def step_ms(result) -> list:
+    """Each step's time at the loop's own boundary: the history records
+    every step once its loss is read back (``log_every=1``), so
+    consecutive record times bound each step from dispatch to done."""
+    walls = [0.0] + [row["wall"] for row in result.history
+                     if "wall" in row]
+    return [round(1e3 * (b - a), 1) for a, b in zip(walls, walls[1:])]
 
 
 def grad_matrix_stats(plan, params):
@@ -203,7 +203,7 @@ def grad_matrix_stats(plan, params):
     return jax.tree.map(np.asarray, out)
 
 
-def train_phase(devices, seed: int, workdir: str) -> None:
+def train_phase(devices, seed: int) -> None:
     cfg = train_config()
     print(f"[train] granite-8b cut: layers {cfg.num_layers} of "
           f"{GRANITE.num_layers}, vocab {cfg.vocab_size} of "
@@ -213,8 +213,7 @@ def train_phase(devices, seed: int, workdir: str) -> None:
           f"{WORKERS} workers x {SEQS_PER_WORKER}x{SEQ_LEN} tokens, "
           f"phocas b={TRIM}, gaussian q={TRIM}, defense on, backend auto",
           flush=True)
-    telemetry = os.path.join(workdir, "train.jsonl")
-    plan = train_plan(steps=TRAIN_STEPS, mesh="", telemetry=telemetry,
+    plan = train_plan(steps=TRAIN_STEPS, mesh="",
                       seed=seed)
     n_params = sum(math.prod(x.shape) for x in
                    jax.tree.leaves(step_shapes(plan)[0]))
@@ -240,9 +239,9 @@ def train_phase(devices, seed: int, workdir: str) -> None:
     check(len(losses) >= TRAIN_STEPS
           and all(math.isfinite(x) for x in losses),
           f"{TRAIN_STEPS} finite train losses")
-    steps_ms = span_ms(telemetry, "train_step")
-    reading("train step ms (block_until_ready; the first includes the "
-            f"jit's compile or cache load): {steps_ms}")
+    reading("train step ms (dispatch to loss read back; the first "
+            "includes the jit's compile or cache load): "
+            f"{step_ms(result)}")
     reading(f"peak_bytes_in_use after train: {peak_bytes(devices)}")
 
     err, scale, p_counts, x_counts = grad_matrix_stats(plan, result.params)
@@ -261,21 +260,24 @@ def train_phase(devices, seed: int, workdir: str) -> None:
 # Serve: the paged ServeEngine, single model and k robust replicas
 # ---------------------------------------------------------------------------
 
-def serve_engine_run(model, params, prompts, telemetry: str, decoder=None):
-    with make_recorder(telemetry, ObsConfig(enabled=True, trace=True,
-                                            profile_cost=False)) as rec:
-        engine = ServeEngine(model, params, max_slots=len(prompts),
-                             max_seq_len=PROMPT_LEN + NEW_TOKENS,
-                             decoder=decoder, telemetry=rec)
-        for p in prompts:
-            engine.submit(p, NEW_TOKENS)
+def serve_engine_run(model, params, prompts, decoder=None):
+    """Serve ``prompts`` to completion; returns the finished requests and
+    each engine step's time in ms (a step returns once its tokens are
+    read back)."""
+    engine = ServeEngine(model, params, max_slots=len(prompts),
+                         max_seq_len=PROMPT_LEN + NEW_TOKENS,
+                         decoder=decoder)
+    for p in prompts:
+        engine.submit(p, NEW_TOKENS)
+    steps = []
+    while engine.scheduler.busy:
         t0 = time.perf_counter()
-        done = engine.run()
-        wall = time.perf_counter() - t0
-    return done, wall
+        engine.step()
+        steps.append(1e3 * (time.perf_counter() - t0))
+    return engine.run(), steps
 
 
-def serve_phase(devices, seed: int, workdir: str) -> None:
+def serve_phase(devices, seed: int) -> None:
     cfg = dataclasses.replace(GRANITE, name="granite-8b-serve-cut",
                               num_layers=SERVE_LAYERS)
     print(f"[serve] granite-8b cut: layers {cfg.num_layers} of "
@@ -298,20 +300,17 @@ def serve_phase(devices, seed: int, workdir: str) -> None:
             reps = corrupt_replica(make_replicas(params, REPLICAS),
                                    REPLICAS - 1,
                                    jax.random.PRNGKey(seed + 1))
-        telemetry = os.path.join(workdir, f"serve_{name}.jsonl")
-        done, wall = serve_engine_run(model, reps, prompts, telemetry,
-                                      decoder)
+        done, steps = serve_engine_run(model, reps, prompts, decoder)
         check(len(done) == REQUESTS
               and all(len(r.generated) == NEW_TOKENS for r in done),
               f"{label}: all {REQUESTS} requests complete with "
               f"{NEW_TOKENS} tokens")
-        prefill = span_ms(telemetry, "prefill")
-        decode = span_ms(telemetry, "decode")
-        reading(f"{label}: {REQUESTS} requests in {wall:.2f} s; prefill ms "
-                f"(first includes compile) {[round(x, 1) for x in prefill]}; "
-                f"decode step ms first {decode[0]:.1f} (compile), median of "
-                f"the rest {float(np.median(decode[1:])):.2f}; request "
-                f"latency ms max {max(r.latency_ms() for r in done):.1f}")
+        reading(f"{label}: {REQUESTS} requests in {sum(steps) / 1e3:.2f} "
+                f"s; engine step ms (to its tokens read back) first "
+                f"{steps[0]:.1f} (the prefill and the first decode, with "
+                f"their compiles), median of the decode steps after it "
+                f"{float(np.median(steps[1:])):.2f}; request latency ms max "
+                f"{max(r.latency_ms() for r in done):.1f}")
         if decoder is not None:
             check(REPLICAS - 1 in decoder.ejected_replicas(),
                   f"corrupted replica {REPLICAS - 1} ejected "
@@ -323,7 +322,7 @@ def serve_phase(devices, seed: int, workdir: str) -> None:
 # Four chips: the sharded robust reduce-scatter against the one-device step
 # ---------------------------------------------------------------------------
 
-def mesh_phase(devices, seed: int, workdir: str) -> None:
+def mesh_phase(devices, seed: int) -> None:
     mesh = f"{WORKERS}x1"
     # No attack here: the sharded layout draws each slice's noise from its
     # own key, so the two runs would trim different coordinates wherever the
@@ -337,11 +336,10 @@ def mesh_phase(devices, seed: int, workdir: str) -> None:
 
     def run(name, steps, m):
         plan = train_plan(steps=steps, mesh=m, seed=seed, q=0,
-                          optimizer="momentum",
-                          telemetry=os.path.join(workdir, f"{name}.jsonl"))
+                          optimizer="momentum")
         result = make_topology(plan.topology).run(plan)
-        reading(f"{name} step ms (the first includes compile or cache "
-                f"load): {span_ms(plan.telemetry_path, 'train_step')}")
+        reading(f"{name} step ms (dispatch to loss read back; the first "
+                f"includes compile or cache load): {step_ms(result)}")
         return result
 
     result = run("sharded", TRAIN_STEPS, mesh)
@@ -384,12 +382,11 @@ def main() -> None:
 
     devices = find_devices(args.chips)
     print(f"[compile cache] {enable_compile_cache()}", flush=True)
-    with tempfile.TemporaryDirectory() as workdir:
-        if args.chips == 4:
-            mesh_phase(devices, args.seed, workdir)
-        else:
-            train_phase(devices, args.seed, workdir)
-            serve_phase(devices, args.seed, workdir)
+    if args.chips == 4:
+        mesh_phase(devices, args.seed)
+    else:
+        train_phase(devices, args.seed)
+        serve_phase(devices, args.seed)
     d0 = devices[0]
     print(json.dumps({"ok": True, "device": {
         "platform": d0.platform, "kind": d0.device_kind,

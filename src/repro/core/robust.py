@@ -113,20 +113,24 @@ def aggregate_matrix(u: jax.Array, cfg: RobustConfig,
     and be readmitted while still misbehaving (eject/readmit flapping).
     Readmission must be earned by actually-clean submissions."""
     attack = make_attack(cfg.attack)
-    uf = u.astype(cfg.agg_dtype)
+    with jax.named_scope("stack"):
+        uf = u.astype(cfg.agg_dtype)
     if attack is not None:
         if key is None:
             raise ValueError("attack configured but no PRNG key supplied")
-        uf = attack(key, uf, step)
+        with jax.named_scope("attack"):
+            uf = attack(key, uf, step)
     rule = cfg.rule_obj()
     if with_scores:
         # One fused hook: raw-submission scores + gated aggregate.  The
         # registry default composes the old two-pass path; the trim-family
         # rules override it with a single shared selection pass.
-        return rule.reduce_gated_with_scores(uf, active)
+        with jax.named_scope("rule"):
+            return rule.reduce_gated_with_scores(uf, active)
     if active is not None:
         uf = gate_matrix(uf, active)
-    return rule.reduce(uf)
+    with jax.named_scope("rule"):
+        return rule.reduce(uf)
 
 
 def aggregate_stacked_tree(stacked, cfg: RobustConfig,
@@ -143,9 +147,11 @@ def aggregate_stacked_tree(stacked, cfg: RobustConfig,
     leaves = jax.tree_util.tree_leaves(stacked)
     m = leaves[0].shape[0]
     # ravel each worker's slice identically
-    flat0, unravel = ravel_pytree(jax.tree.map(lambda x: x[0], stacked))
-    mat = jax.vmap(lambda i: ravel_pytree(
-        jax.tree.map(lambda x: x[i], stacked))[0])(jnp.arange(m))
+    with jax.named_scope("stack"):
+        flat0, unravel = ravel_pytree(jax.tree.map(lambda x: x[0],
+                                                   stacked))
+        mat = jax.vmap(lambda i: ravel_pytree(
+            jax.tree.map(lambda x: x[i], stacked))[0])(jnp.arange(m))
     out = aggregate_matrix(mat, cfg, key, active=active,
                            with_scores=with_scores, step=step)
     if with_scores:
@@ -189,12 +195,13 @@ def robust_aggregate_dist(grad_tree, cfg: RobustConfig,
     """
     worker_axes = tuple(worker_axes)
     m = _axis_size(worker_axes)
-    flat, unravel = ravel_pytree(grad_tree)
-    flat = flat.astype(cfg.agg_dtype)
-    d = flat.shape[0]
-    pad = (-d) % m
-    if pad:
-        flat = jnp.pad(flat, (0, pad))
+    with jax.named_scope("stack"):
+        flat, unravel = ravel_pytree(grad_tree)
+        flat = flat.astype(cfg.agg_dtype)
+        d = flat.shape[0]
+        pad = (-d) % m
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
 
     attack = make_attack(cfg.attack)
     rule = cfg.rule_obj()
@@ -204,16 +211,19 @@ def robust_aggregate_dist(grad_tree, cfg: RobustConfig,
         # matrix (see aggregate_matrix: prevents eject/readmit flapping).
         # Both come out of the one fused hook.
         if with_scores:
-            return rule.reduce_sharded_gated_with_scores(mat, active,
-                                                         psum_axes)
+            with jax.named_scope("rule"):
+                return rule.reduce_sharded_gated_with_scores(mat, active,
+                                                             psum_axes)
         if active is not None:
             mat = gate_matrix(mat, active)
-        return rule.reduce_sharded(mat, psum_axes), None
+        with jax.named_scope("rule"):
+            return rule.reduce_sharded(mat, psum_axes), None
 
     if cfg.layout == "replicated":
         mat = _gather_workers(flat, worker_axes)          # (m, D)
         if attack is not None:
-            mat = attack(key, mat, step)
+            with jax.named_scope("attack"):
+                mat = attack(key, mat, step)
         agg, scores = _reduce(mat, tuple(model_axes))      # (D,)
     elif cfg.layout == "sharded":
         mat = _a2a_scatter(flat, worker_axes)             # (m, D/m)
@@ -222,7 +232,8 @@ def robust_aggregate_dist(grad_tree, cfg: RobustConfig,
             # the paper's §5.1.4 multi-server partitioning.
             key = jax.random.fold_in(key, _worker_slice_index(worker_axes)) \
                 if key is not None else None
-            mat = attack(key, mat, step)
+            with jax.named_scope("attack"):
+                mat = attack(key, mat, step)
         agg_slice, scores = _reduce(
             mat, worker_axes + tuple(model_axes))         # (D/m,)
         agg = _gather_slices(agg_slice, worker_axes)      # (D,)

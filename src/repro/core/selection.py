@@ -341,6 +341,7 @@ def gate_matrix(mat: jax.Array, active: jax.Array) -> jax.Array:
         import numpy as np
         if bool(np.all(np.asarray(active) > 0)):
             return mat
-    med = matrix_median(mat)
-    keep = active.reshape((mat.shape[0],) + (1,) * (mat.ndim - 1))
-    return jnp.where(keep > 0, mat, med[None].astype(mat.dtype))
+    with jax.named_scope("gate"):
+        med = matrix_median(mat)
+        keep = active.reshape((mat.shape[0],) + (1,) * (mat.ndim - 1))
+        return jnp.where(keep > 0, mat, med[None].astype(mat.dtype))

@@ -70,15 +70,26 @@ def _defense_gauges(rec, *, rule_name: str, m: int, q_hat: int,
         rec.gauge("delta_bound_unit_var", bound, rule=rule_name)
 
 
-def _profile_step_cost(rec, plan: Plan, step_fn, args) -> None:
-    """One-shot FLOPs/bytes gauges for the compiled train step (AOT lower
-    + compile — an extra compile, so gated on obs.profile_cost)."""
-    if not (rec.metrics_enabled and plan.obs is not None
-            and getattr(plan.obs, "profile_cost", False)):
+def _profile_step(rec, plan: Plan, step_fn, args) -> None:
+    """One AOT compile of the train step (a cache hit where the persistent
+    compilation cache holds it), read twice: FLOPs/bytes gauges when
+    metrics and ``obs.profile_cost`` are on, and the scope of each HLO
+    instruction (``rec.scopes``, keyed by the module's name) when tracing
+    is on, so a profiler trace's ops can be put down to the step's named
+    scopes."""
+    cost = (rec.metrics_enabled and plan.obs is not None
+            and getattr(plan.obs, "profile_cost", False))
+    if not (cost or rec.trace_enabled):
         return
-    from repro.obs.profile import compiled_cost
-    for name, v in compiled_cost(step_fn, *args).items():
-        rec.gauge(f"step_{name}", v)
+    from repro.obs.profile import compiled_cost, hlo_scopes
+    compiled = step_fn.lower(*args).compile()
+    if cost:
+        for name, v in compiled_cost(compiled).items():
+            rec.gauge(f"step_{name}", v)
+    if rec.trace_enabled:
+        text = compiled.as_text()
+        module = text.split(",", 1)[0].removeprefix("HloModule ").strip()
+        rec.scopes[module] = hlo_scopes(text)
 
 
 @register_topology
@@ -216,7 +227,8 @@ class SyncPS(Topology):
                     print(f"resumed from {src} at step {ck_step} "
                           f"({plan.resume_path})", flush=True)
             for step in range(start_step, plan.steps):
-                batch = make_worker_batches(plan.batch_fn(step), m)
+                with rec.span("sync_ps/input", step_num=step):
+                    batch = make_worker_batches(plan.batch_fn(step), m)
                 key, sk = jax.random.split(key)
                 fr = injector.collect(step) if injector is not None else None
                 if fr is not None:
@@ -238,7 +250,8 @@ class SyncPS(Topology):
                             crashed=fr.crashed, retries=fr.retries,
                             timeouts=fr.timeouts, lost_round=True)
                     continue
-                if fr is not None and fr.degraded:
+                degraded = fr is not None and fr.degraded
+                if degraded:
                     rc_eff, q_atk = resolve_quorum(robust_cfg, fr.present)
                     ck = (fr.m_eff, rc_eff.b, rc_eff.q, q_atk)
                     fn = fault_steps.get(ck)
@@ -258,165 +271,138 @@ class SyncPS(Topology):
                     sub_r = (resid[idx]
                              if codec is not None and codec.stateful
                              else resid)
-                    with rec.span("degraded_round", step_num=step,
-                                  rule=rc_eff.rule) as sp:
-                        if defense_state is not None:
-                            sub = {k: (v[idx] if getattr(v, "ndim", 0) == 1
-                                       else v)
-                                   for k, v in defense_state.items()}
-                            (params, opt_state, sub, sub_r, metrics) = \
-                                sp.sync(invoke(fn, params, opt_state,
-                                               cbatch, sk, sub, sub_r))
-                            defense_state = _scatter_defense(
-                                defense_state, sub, idx)
-                            metrics = {**metrics,
-                                       "suspicion": _scatter_vec(
-                                           metrics["suspicion"], idx, m),
-                                       "reputation":
-                                           defense_state["reputation"],
-                                       "active": defense_state["active"]}
-                            rec.log("train", step,
-                                    loss=metrics["loss"],
-                                    grad_norm=metrics["grad_norm"],
-                                    suspicion=metrics["suspicion"],
-                                    reputation=metrics["reputation"],
-                                    active=metrics["active"],
-                                    q_hat=metrics["q_hat"])
-                            if rec.metrics_enabled:
-                                prev_active = _mask_flips(
-                                    rec, prev_active, metrics["active"],
-                                    "train")
-                                _defense_gauges(
-                                    rec, rule_name=rc_eff.rule,
-                                    m=fr.m_eff,
-                                    q_hat=int(metrics["q_hat"]),
-                                    b=rc_eff.b, q=rc_eff.q)
-                        else:
-                            (params, opt_state, _, sub_r, metrics) = \
-                                sp.sync(invoke(fn, params, opt_state,
-                                               cbatch, sk, None, sub_r))
+                    sub = ({k: (v[idx] if getattr(v, "ndim", 0) == 1
+                                else v)
+                            for k, v in defense_state.items()}
+                           if defense_state is not None else None)
+                    with rec.span("sync_ps/dispatch", step_num=step,
+                                  rule=rc_eff.rule):
+                        (params, opt_state, sub, sub_r, metrics) = invoke(
+                            fn, params, opt_state, cbatch, sk, sub, sub_r)
+                    if defense_state is not None:
+                        defense_state = _scatter_defense(
+                            defense_state, sub, idx)
+                        metrics = {**metrics,
+                                   "suspicion": _scatter_vec(
+                                       metrics["suspicion"], idx, m),
+                                   "reputation": defense_state["reputation"],
+                                   "active": defense_state["active"]}
                     if codec is not None:
                         resid = (resid.at[idx].set(sub_r)
                                  if codec.stateful else sub_r)
-                elif defense_state is not None:
-                    if not profiled_cost:
-                        profiled_cost = True
-                        args = (params, opt_state, batch, sk, defense_state)
-                        if codec is not None:
-                            args = args + (resid,)
-                        _profile_step_cost(rec, plan, step_fn, args)
-                    with rec.span("train_step", step_num=step,
-                                  rule=robust_cfg.rule) as sp:
-                        (params, opt_state, defense_state, resid,
-                         metrics) = sp.sync(invoke(
-                             step_fn, params, opt_state, batch, sk,
-                             defense_state, resid))
-                    rec.log("train", step,
-                            loss=metrics["loss"],
-                            grad_norm=metrics["grad_norm"],
-                            suspicion=metrics["suspicion"],
-                            reputation=metrics["reputation"],
-                            active=metrics["active"],
-                            q_hat=metrics["q_hat"])
-                    if rec.metrics_enabled:
-                        prev_active = _mask_flips(
-                            rec, prev_active, metrics["active"], "train")
-                        _defense_gauges(
-                            rec, rule_name=robust_cfg.rule, m=m,
-                            q_hat=int(metrics["q_hat"]), b=robust_cfg.b,
-                            q=robust_cfg.q)
                 else:
                     if not profiled_cost:
                         profiled_cost = True
                         args = (params, opt_state, batch, sk)
+                        if defense_state is not None:
+                            args = args + (defense_state,)
                         if codec is not None:
                             args = args + (resid,)
-                        _profile_step_cost(rec, plan, step_fn, args)
-                    with rec.span("train_step", step_num=step,
-                                  rule=robust_cfg.rule) as sp:
-                        (params, opt_state, _, resid, metrics) = sp.sync(
-                            invoke(step_fn, params, opt_state, batch, sk,
-                                   None, resid))
-                rec.count("steps", topology=self.name)
-                if codec is not None:
-                    m_r = fr.m_eff if fr is not None else m
-                    rt = fr.retries if fr is not None else 0
-                    sent = bytes_per_round(codec, dense_dim, m_r, rt)
-                    dense = 4 * dense_dim * (m_r + rt)
-                    rec.log("compress", step, codec=codec.name,
-                            bytes=sent, dense_bytes=dense,
-                            ratio=sent / max(dense, 1))
-
-                if step % plan.record_every == 0 or step == plan.steps - 1:
-                    row = {"step": step, "loss": float(metrics["loss"]),
-                           "grad_norm": float(metrics["grad_norm"]),
-                           "wall": time.time() - t0}
-                    if fr is not None:
-                        row["present"] = int(fr.m_eff)
-                    if "q_hat" in metrics:
-                        row["q_hat"] = int(metrics["q_hat"])
-                        row["n_active"] = int(jnp.sum(metrics["active"]))
-                    if plan.eval_fn is not None:
-                        row["eval"] = float(plan.eval_fn(params))
-                    history.append(row)
-                    if rec.metrics_enabled:
-                        from repro.obs.profile import sample_into
-                        sample_into(rec)
-                    if plan.verbose:
-                        msg = (f"step {step:5d}  loss {row['loss']:.4f}  "
-                               f"gnorm {row['grad_norm']:.3e}")
-                        if "q_hat" in row:
-                            msg += (f"  qhat {row['q_hat']}  "
-                                    f"active {row['n_active']}")
-                        if "eval" in row:
-                            msg += f"  eval {row['eval']:.4f}"
-                        print(msg, flush=True)
-
-                if (plan.checkpoint_path and plan.checkpoint_every and step
-                        and step % plan.checkpoint_every == 0):
-                    from repro.checkpoint.io import save_checkpoint
-                    # "key" is the loop key AFTER this step's split, and
-                    # "rule" the live (possibly adapted) b/q — together
-                    # they make --resume continue bit-for-bit.
-                    tree = {"params": params, "opt": opt_state,
-                            "key": key, "rule": _rule_tree(robust_cfg)}
+                        _profile_step(rec, plan, step_fn, args)
+                    with rec.span("sync_ps/dispatch", step_num=step,
+                                  rule=robust_cfg.rule):
+                        (params, opt_state, defense_state, resid,
+                         metrics) = invoke(step_fn, params, opt_state, batch,
+                                           sk, defense_state, resid)
+                with rec.span("sync_ps/record", step_num=step):
                     if defense_state is not None:
-                        tree["defense"] = defense_state
-                    if codec is not None and codec.stateful:
-                        # EF residual is run state: dropping it on resume
-                        # would re-inject already-compensated error
-                        tree["compress"] = resid
-                    save_checkpoint(plan.checkpoint_path, tree, step=step)
+                        rec.log("train", step,
+                                loss=metrics["loss"],
+                                grad_norm=metrics["grad_norm"],
+                                suspicion=metrics["suspicion"],
+                                reputation=metrics["reputation"],
+                                active=metrics["active"],
+                                q_hat=metrics["q_hat"])
+                    if defense_state is not None and rec.metrics_enabled:
+                        rc_now = rc_eff if degraded else robust_cfg
+                        prev_active = _mask_flips(
+                            rec, prev_active, metrics["active"], "train")
+                        _defense_gauges(
+                            rec, rule_name=rc_now.rule,
+                            m=fr.m_eff if degraded else m,
+                            q_hat=int(metrics["q_hat"]), b=rc_now.b,
+                            q=rc_now.q)
+                    rec.count("steps", topology=self.name)
+                    if codec is not None:
+                        m_r = fr.m_eff if fr is not None else m
+                        rt = fr.retries if fr is not None else 0
+                        sent = bytes_per_round(codec, dense_dim, m_r, rt)
+                        dense = 4 * dense_dim * (m_r + rt)
+                        rec.log("compress", step, codec=codec.name,
+                                bytes=sent, dense_bytes=dense,
+                                ratio=sent / max(dense, 1))
 
-                if adapt:
-                    q_hat = int(metrics["q_hat"])
-                    current = (robust_cfg.b if rule_meta.uses_b
-                               else robust_cfg.q)
-                    pending = pending + 1 if q_hat > current else 0
-                    if pending >= dcfg.adapt_patience:
-                        new_b = (min(q_hat, bmax) if rule_meta.uses_b
-                                 else robust_cfg.b)
-                        new_q = (min(max(q_hat, robust_cfg.q), m - 3)
-                                 if rule_meta.uses_q else robust_cfg.q)
-                        pending = 0
-                        # q̂ beyond the cap leaves b/q saturated — nothing
-                        # to re-jit, and refiring every patience window
-                        # would recompile an unchanged step forever.
-                        if (new_b != robust_cfg.b
-                                or new_q != robust_cfg.q):
-                            robust_cfg = dataclasses.replace(
-                                robust_cfg, b=new_b, q=new_q)
-                            step_fn = build_step(robust_cfg)
-                            history.append(
-                                {"step": step, "adapted_b": new_b,
-                                 "adapted_q": new_q, "q_hat": q_hat})
-                            rec.log("adapt", step, b=new_b, q=new_q,
-                                    q_hat=q_hat)
-                            rec.count("adaptations")
-                            if plan.verbose:
-                                print(f"step {step:5d}  [adapt] "
-                                      f"q_hat={q_hat} -> b={new_b} "
-                                      f"q={new_q} (re-jit)", flush=True)
+                    if step % plan.record_every == 0 or step == plan.steps - 1:
+                        row = {"step": step, "loss": float(metrics["loss"]),
+                               "grad_norm": float(metrics["grad_norm"]),
+                               "wall": time.time() - t0}
+                        if fr is not None:
+                            row["present"] = int(fr.m_eff)
+                        if "q_hat" in metrics:
+                            row["q_hat"] = int(metrics["q_hat"])
+                            row["n_active"] = int(jnp.sum(metrics["active"]))
+                        if plan.eval_fn is not None:
+                            row["eval"] = float(plan.eval_fn(params))
+                        history.append(row)
+                        if rec.metrics_enabled:
+                            from repro.obs.profile import sample_into
+                            sample_into(rec)
+                        if plan.verbose:
+                            msg = (f"step {step:5d}  loss {row['loss']:.4f}  "
+                                   f"gnorm {row['grad_norm']:.3e}")
+                            if "q_hat" in row:
+                                msg += (f"  qhat {row['q_hat']}  "
+                                        f"active {row['n_active']}")
+                            if "eval" in row:
+                                msg += f"  eval {row['eval']:.4f}"
+                            print(msg, flush=True)
+
+                    if (plan.checkpoint_path and plan.checkpoint_every and step
+                            and step % plan.checkpoint_every == 0):
+                        from repro.checkpoint.io import save_checkpoint
+                        # "key" is the loop key AFTER this step's split, and
+                        # "rule" the live (possibly adapted) b/q — together
+                        # they make --resume continue bit-for-bit.
+                        tree = {"params": params, "opt": opt_state,
+                                "key": key, "rule": _rule_tree(robust_cfg)}
+                        if defense_state is not None:
+                            tree["defense"] = defense_state
+                        if codec is not None and codec.stateful:
+                            # EF residual is run state: dropping it on resume
+                            # would re-inject already-compensated error
+                            tree["compress"] = resid
+                        save_checkpoint(plan.checkpoint_path, tree, step=step)
+
+                    if adapt:
+                        q_hat = int(metrics["q_hat"])
+                        current = (robust_cfg.b if rule_meta.uses_b
+                                   else robust_cfg.q)
+                        pending = pending + 1 if q_hat > current else 0
+                        if pending >= dcfg.adapt_patience:
+                            new_b = (min(q_hat, bmax) if rule_meta.uses_b
+                                     else robust_cfg.b)
+                            new_q = (min(max(q_hat, robust_cfg.q), m - 3)
+                                     if rule_meta.uses_q else robust_cfg.q)
+                            pending = 0
+                            # q̂ beyond the cap leaves b/q saturated —
+                            # nothing to re-jit, and refiring every patience
+                            # window would recompile an unchanged step
+                            # forever.
+                            if (new_b != robust_cfg.b
+                                    or new_q != robust_cfg.q):
+                                robust_cfg = dataclasses.replace(
+                                    robust_cfg, b=new_b, q=new_q)
+                                step_fn = build_step(robust_cfg)
+                                history.append(
+                                    {"step": step, "adapted_b": new_b,
+                                     "adapted_q": new_q, "q_hat": q_hat})
+                                rec.log("adapt", step, b=new_b, q=new_q,
+                                        q_hat=q_hat)
+                                rec.count("adaptations")
+                                if plan.verbose:
+                                    print(f"step {step:5d}  [adapt] "
+                                          f"q_hat={q_hat} -> b={new_b} "
+                                          f"q={new_q} (re-jit)", flush=True)
             wall = time.time() - t0
             rec.gauge("steps_per_sec",
                       (plan.steps - start_step) / max(wall, 1e-9),
@@ -501,7 +487,8 @@ class AsyncPS(Topology):
         t0 = time.time()
         with make_recorder(plan.telemetry_path, plan.obs) as rec:
             for i in range(plan.steps):
-                batch = make_worker_batches(plan.batch_fn(i), m)
+                with rec.span("async_ps/input", step_num=i):
+                    batch = make_worker_batches(plan.batch_fn(i), m)
                 if injector is not None:
                     fr = injector.collect(i)
                     present = jnp.asarray(fr.present, jnp.float32)
@@ -510,11 +497,11 @@ class AsyncPS(Topology):
                         rec.count("fault_retries", fr.retries)
                     if fr.timeouts:
                         rec.count("fault_timeouts", fr.timeouts)
-                    with rec.span("async_step", step_num=i,
-                                  rule=plan.robust_cfg.rule) as sp:
-                        state, metrics = sp.sync(step_fn(
+                    with rec.span("async_ps/dispatch", step_num=i,
+                                  rule=plan.robust_cfg.rule):
+                        state, metrics = step_fn(
                             state, batch, jax.random.fold_in(key, i),
-                            present))
+                            present)
                     rec.log("fault", i, present=int(fr.m_eff),
                             crashed=fr.crashed, retries=fr.retries,
                             timeouts=fr.timeouts,
@@ -524,45 +511,46 @@ class AsyncPS(Topology):
                         state["defense"] = update_presence(
                             state["defense"], present, plan.defense_cfg)
                 else:
-                    with rec.span("async_step", step_num=i,
-                                  rule=plan.robust_cfg.rule) as sp:
-                        state, metrics = sp.sync(step_fn(
-                            state, batch, jax.random.fold_in(key, i)))
-                rec.count("steps", topology=self.name)
-                if codec is not None:
-                    rt = fr.retries if injector is not None else 0
-                    sent = bytes_per_round(codec, dense_dim, m, rt)
-                    dense = 4 * dense_dim * (m + rt)
-                    rec.log("compress", i, codec=codec.name, bytes=sent,
-                            dense_bytes=dense, ratio=sent / max(dense, 1))
-                if plan.defense_cfg is not None:
-                    rec.log("async", i,
-                            staleness_frac=metrics["staleness_frac"],
-                            suspicion=metrics["suspicion"],
-                            reputation=metrics["reputation"],
-                            active=metrics["active"],
-                            q_hat=metrics["q_hat"])
-                    if rec.metrics_enabled:
-                        prev_active = _mask_flips(
-                            rec, prev_active, metrics["active"], "async")
-                        _defense_gauges(
-                            rec, rule_name=plan.robust_cfg.rule, m=m,
-                            q_hat=int(metrics["q_hat"]),
-                            b=plan.robust_cfg.b, q=plan.robust_cfg.q)
-                if i % plan.record_every == 0 or i == plan.steps - 1:
-                    row = {"step": i, "staleness_frac":
-                           float(metrics["staleness_frac"])}
-                    if injector is not None:
-                        row["present"] = int(fr.m_eff)
-                        row["m_fresh"] = int(metrics["m_fresh"])
-                    if "q_hat" in metrics:
-                        row["q_hat"] = int(metrics["q_hat"])
-                    if plan.eval_fn is not None:
-                        row["eval"] = float(plan.eval_fn(state["params"]))
-                    history.append(row)
-                    if plan.verbose and "eval" in row:
-                        print(f"step {i:5d}  eval {row['eval']:.4f}",
-                              flush=True)
+                    with rec.span("async_ps/dispatch", step_num=i,
+                                  rule=plan.robust_cfg.rule):
+                        state, metrics = step_fn(
+                            state, batch, jax.random.fold_in(key, i))
+                with rec.span("async_ps/record", step_num=i):
+                    rec.count("steps", topology=self.name)
+                    if codec is not None:
+                        rt = fr.retries if injector is not None else 0
+                        sent = bytes_per_round(codec, dense_dim, m, rt)
+                        dense = 4 * dense_dim * (m + rt)
+                        rec.log("compress", i, codec=codec.name, bytes=sent,
+                                dense_bytes=dense, ratio=sent / max(dense, 1))
+                    if plan.defense_cfg is not None:
+                        rec.log("async", i,
+                                staleness_frac=metrics["staleness_frac"],
+                                suspicion=metrics["suspicion"],
+                                reputation=metrics["reputation"],
+                                active=metrics["active"],
+                                q_hat=metrics["q_hat"])
+                        if rec.metrics_enabled:
+                            prev_active = _mask_flips(
+                                rec, prev_active, metrics["active"], "async")
+                            _defense_gauges(
+                                rec, rule_name=plan.robust_cfg.rule, m=m,
+                                q_hat=int(metrics["q_hat"]),
+                                b=plan.robust_cfg.b, q=plan.robust_cfg.q)
+                    if i % plan.record_every == 0 or i == plan.steps - 1:
+                        row = {"step": i, "staleness_frac":
+                               float(metrics["staleness_frac"])}
+                        if injector is not None:
+                            row["present"] = int(fr.m_eff)
+                            row["m_fresh"] = int(metrics["m_fresh"])
+                        if "q_hat" in metrics:
+                            row["q_hat"] = int(metrics["q_hat"])
+                        if plan.eval_fn is not None:
+                            row["eval"] = float(plan.eval_fn(state["params"]))
+                        history.append(row)
+                        if plan.verbose and "eval" in row:
+                            print(f"step {i:5d}  eval {row['eval']:.4f}",
+                                  flush=True)
             wall = time.time() - t0
             rec.gauge("steps_per_sec", plan.steps / max(wall, 1e-9),
                       topology=self.name)
@@ -619,7 +607,8 @@ class Streaming(Topology):
         t0 = time.time()
         with make_recorder(plan.telemetry_path, plan.obs) as rec:
             for i in range(plan.steps):
-                batch = make_worker_batches(plan.batch_fn(i), m)
+                with rec.span("streaming/input", step_num=i):
+                    batch = make_worker_batches(plan.batch_fn(i), m)
                 fr = injector.collect(i) if injector is not None else None
                 if fr is not None:
                     rec.gauge("present_workers", fr.m_eff)
@@ -647,40 +636,41 @@ class Streaming(Topology):
                             crashed=fr.crashed, retries=fr.retries,
                             timeouts=fr.timeouts, b_eff=rc_eff.b,
                             q_eff=rc_eff.q)
-                    with rec.span("degraded_round", step_num=i,
-                                  rule=rc_eff.rule) as sp:
-                        params, opt_state, metrics = sp.sync(fn(
+                    with rec.span("streaming/dispatch", step_num=i,
+                                  rule=rc_eff.rule):
+                        params, opt_state, metrics = fn(
                             params, opt_state, cbatch,
-                            jax.random.fold_in(key, i)))
+                            jax.random.fold_in(key, i))
                 else:
-                    with rec.span("streaming_step", step_num=i,
-                                  rule=plan.robust_cfg.rule) as sp:
-                        params, opt_state, metrics = sp.sync(step_fn(
+                    with rec.span("streaming/dispatch", step_num=i,
+                                  rule=plan.robust_cfg.rule):
+                        params, opt_state, metrics = step_fn(
                             params, opt_state, batch,
-                            jax.random.fold_in(key, i)))
-                rec.count("steps", topology=self.name)
-                if codec is not None:
-                    m_r = fr.m_eff if fr is not None else m
-                    rt = fr.retries if fr is not None else 0
-                    sent = bytes_per_round(codec, dense_dim, m_r, rt)
-                    dense = 4 * dense_dim * (m_r + rt)
-                    rec.log("compress", i, codec=codec.name, bytes=sent,
-                            dense_bytes=dense, ratio=sent / max(dense, 1))
-                extra = ({"suspicion": metrics["suspicion"]}
-                         if "suspicion" in metrics else {})
-                rec.log("streaming", i, loss=metrics["loss"], **extra)
-                if i % plan.record_every == 0 or i == plan.steps - 1:
-                    row = {"step": i, "loss": float(metrics["loss"])}
-                    if fr is not None:
-                        row["present"] = int(fr.m_eff)
-                    if plan.eval_fn is not None:
-                        row["eval"] = float(plan.eval_fn(params))
-                    history.append(row)
-                    if plan.verbose:
-                        msg = f"step {i:5d}  loss {row['loss']:.4f}"
-                        if "eval" in row:
-                            msg += f"  eval {row['eval']:.4f}"
-                        print(msg, flush=True)
+                            jax.random.fold_in(key, i))
+                with rec.span("streaming/record", step_num=i):
+                    rec.count("steps", topology=self.name)
+                    if codec is not None:
+                        m_r = fr.m_eff if fr is not None else m
+                        rt = fr.retries if fr is not None else 0
+                        sent = bytes_per_round(codec, dense_dim, m_r, rt)
+                        dense = 4 * dense_dim * (m_r + rt)
+                        rec.log("compress", i, codec=codec.name, bytes=sent,
+                                dense_bytes=dense, ratio=sent / max(dense, 1))
+                    extra = ({"suspicion": metrics["suspicion"]}
+                             if "suspicion" in metrics else {})
+                    rec.log("streaming", i, loss=metrics["loss"], **extra)
+                    if i % plan.record_every == 0 or i == plan.steps - 1:
+                        row = {"step": i, "loss": float(metrics["loss"])}
+                        if fr is not None:
+                            row["present"] = int(fr.m_eff)
+                        if plan.eval_fn is not None:
+                            row["eval"] = float(plan.eval_fn(params))
+                        history.append(row)
+                        if plan.verbose:
+                            msg = f"step {i:5d}  loss {row['loss']:.4f}"
+                            if "eval" in row:
+                                msg += f"  eval {row['eval']:.4f}"
+                            print(msg, flush=True)
             wall = time.time() - t0
             rec.gauge("steps_per_sec", plan.steps / max(wall, 1e-9),
                       topology=self.name)
@@ -819,7 +809,7 @@ class Serve(Topology):
                         "queued": engine.scheduler.queued,
                         "active": len(engine.scheduler.active),
                         "tokens": produced})
-            engine.scheduler.retire_finished()
+            engine.retire_finished()
 
         wall = time.time() - t0
         done = engine.scheduler.completed

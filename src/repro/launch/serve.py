@@ -38,7 +38,7 @@ def _obs_config(args):
     if not (args.metrics or args.profile_dir):
         return None
     from repro.obs import ObsConfig
-    return ObsConfig(enabled=True, trace=True,
+    return ObsConfig(enabled=bool(args.metrics), trace=True,
                      metrics_path=args.metrics or None,
                      profile_dir=args.profile_dir or None)
 

@@ -249,8 +249,9 @@ def main():
                          "exposition snapshot to this path at run end "
                          "(implies span tracing; see repro.obs)")
     ap.add_argument("--profile-dir", default="",
-                    help="capture a jax.profiler trace of the run into "
-                         "this directory (view with TensorBoard)")
+                    help="capture a jax.profiler trace of the run, with "
+                         "the program's spans by name, into this "
+                         "directory (view with TensorBoard)")
     args = ap.parse_args()
     if args.use_kernels:
         print("[train] --use-kernels is deprecated; use --backend pallas")
@@ -275,7 +276,9 @@ def main():
     obs = None
     if args.metrics or args.profile_dir:
         from repro.obs import ObsConfig
-        obs = ObsConfig(enabled=True, trace=True,
+        # a profile alone traces without the metrics registry, whose
+        # defense gauges would read each step back and stall the run-ahead
+        obs = ObsConfig(enabled=bool(args.metrics), trace=True,
                         metrics_path=args.metrics or None,
                         profile_dir=args.profile_dir or None)
 

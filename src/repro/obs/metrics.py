@@ -11,10 +11,11 @@ Two objects:
   ``TelemetryWriter.log``) fans one record out to the JSONL sinks AND
   mirrors its scalar fields into registry gauges; ``count()`` /
   ``gauge()`` / ``observe()`` update metrics directly; ``span()`` returns
-  a timed context manager (``obs/trace.py``) that lands wall-times in the
-  ``span_ms`` histogram.  A disabled Recorder (no sinks, no registry) costs
-  one attribute check per call and allocates nothing — hot loops call it
-  unconditionally, exactly like the old no-path TelemetryWriter.
+  a context manager (``obs/trace.py``) that keeps the host's time in a
+  named span and lands it in the ``span_ms`` histogram.  A disabled
+  Recorder (no sinks, no registry, no tracer) costs one attribute check
+  per call and allocates nothing — hot loops call it unconditionally,
+  exactly like the old no-path TelemetryWriter.
 
 The legacy ``defense/telemetry.TelemetryWriter`` survives unchanged as the
 JSONL *sink backend*: the Recorder writes through it, so the on-disk format
@@ -171,13 +172,19 @@ class MetricsRegistry:
 class ObsConfig:
     """Observability switches a launch CLI maps its flags onto.
 
-    ``enabled`` turns the metrics registry on; ``trace`` additionally arms
-    span timing (host wall-clock with ``block_until_ready`` at span close —
-    see obs/trace.py for the async-dispatch contract); ``metrics_path`` is
-    where the Prometheus-style exposition snapshot lands when the Recorder
-    closes; ``profile_dir`` captures a ``jax.profiler.trace`` window around
-    the run (obs/profile.py); ``profile_cost`` samples per-step FLOPs/bytes
-    from the compiled step via ``cost_analysis()`` (one extra lowering).
+    ``enabled`` turns the metrics registry on (counters, gauges, the
+    ``span_ms`` histogram; the defended loops then read the defense state
+    of each step back to publish q-hat and ejection counters).  ``trace``
+    arms the tracer on its own: spans of the host's work, kept in memory
+    and entered as ``jax.profiler`` annotations, request records, the gc
+    hook, and the scope map of the compiled train step (obs/trace.py).
+    Tracing never reads a value back from the device, so
+    ``ObsConfig(enabled=False, trace=True)`` leaves a loop's schedule as it
+    is.  ``metrics_path`` is where the Prometheus-style exposition snapshot
+    lands when the Recorder closes; ``profile_dir`` captures a
+    ``jax.profiler.trace`` window around the run (obs/profile.py);
+    ``profile_cost`` samples per-step FLOPs/bytes from the compiled step
+    via ``cost_analysis()`` when metrics are on.
     """
     enabled: bool = True
     trace: bool = True
@@ -208,8 +215,14 @@ class Recorder:
     ``sinks`` are TelemetryWriter-shaped objects (anything with
     ``log(kind, step, **fields)``); ``owned`` sinks are closed with the
     Recorder.  ``registry=None`` disables metrics, ``trace=False`` disables
-    span timing — with both off and no sinks, every method is a cheap
-    no-op, which is the mode hot loops run in by default.
+    the tracer — with all off and no sinks, every method is a cheap no-op,
+    which is the mode hot loops run in by default.
+
+    A tracing Recorder keeps in memory what the run's readers take after
+    it: ``spans`` (closed :class:`~repro.obs.trace.SpanRecord` s),
+    ``requests`` (one phase record per retired serving request) and
+    ``scopes`` (HLO module name -> instruction -> named scope, from
+    ``obs/profile.hlo_scopes``).  ``close()`` writes them to the sinks.
     """
 
     def __init__(self, sinks: Sequence = (), registry:
@@ -219,8 +232,22 @@ class Recorder:
         self._sinks = list(sinks)
         self._owned = list(owned)
         self.registry = registry
-        self.trace_enabled = bool(trace) and registry is not None
+        self.trace_enabled = bool(trace)
         self.metrics_path = metrics_path
+        self.spans: list = []
+        self.requests: List[dict] = []
+        self.scopes: Dict[str, Dict[str, str]] = {}
+        self._gc_hook = None
+        if self.trace_enabled:
+            import gc
+
+            # Import the profiler now: a collection can start in the middle
+            # of any import, and the hook must not be the one to import it.
+            import jax.profiler  # noqa: F401
+
+            from repro.obs.trace import GcHook
+            self._gc_hook = GcHook(self)
+            gc.callbacks.append(self._gc_hook)
         self._closed = False
 
     # -- construction ------------------------------------------------------
@@ -231,8 +258,9 @@ class Recorder:
 
     @property
     def enabled(self) -> bool:
-        """Is anything listening (a sink or the registry)?"""
-        return bool(self._sinks) or self.registry is not None
+        """Is anything listening (a sink, the registry or the tracer)?"""
+        return (bool(self._sinks) or self.registry is not None
+                or self.trace_enabled)
 
     @property
     def metrics_enabled(self) -> bool:
@@ -281,25 +309,39 @@ class Recorder:
         if self.registry is not None:
             self.registry.histogram(name, buckets, **labels).observe(value)
 
-    def span(self, name: str, step_num: Optional[int] = None, **labels):
-        """A timed span context manager, or the shared zero-cost no-op
-        when tracing is off (``rec.span(...) is rec.span(...)`` then —
-        nothing is allocated per call)."""
+    def span(self, name: str, step_num: Optional[int] = None,
+             rid: Optional[int] = None, **labels):
+        """A span of the host's work, or the shared zero-cost no-op when
+        tracing is off (``rec.span(...) is rec.span(...)`` then — nothing
+        is allocated per call).  ``step_num`` and ``rid`` (a request id)
+        become arguments of the span's profiler annotation."""
         from repro.obs.trace import NULL_SPAN, Span
         if not self.trace_enabled:
             return NULL_SPAN
-        return Span(self, name, labels, step_num=step_num)
+        return Span(self, name, labels, step_num=step_num, rid=rid)
 
     # trace.Span calls back here when a span closes.
-    def _span_done(self, path: str, ms: float, labels: Dict[str, object],
-                   step_num: Optional[int]) -> None:
+    def _span_done(self, span) -> None:
+        self.spans.append(span)
         if self.registry is not None:
             self.registry.histogram(
                 "span_ms", DEFAULT_MS_BUCKETS,
-                name=path, **labels).observe(ms)
-        if self._sinks:
-            self._write("span", step_num if step_num is not None else -1,
-                        name=path, ms=ms, labels=dict(labels))
+                name=span.path, **span.labels).observe(span.ms)
+
+    def request(self, rid: int, *, t_enqueue: float, t_admitted: float,
+                t_first_token: float, t_done: float) -> None:
+        """One retired request's phases (seconds on ``perf_counter``):
+        queued from enqueue to admission, prefill from admission to the
+        first token, decode from the first token to the last.  Kept while
+        tracing."""
+        if not self.trace_enabled:
+            return
+        self.requests.append({
+            "rid": rid, "t_enqueue": t_enqueue, "t_admitted": t_admitted,
+            "t_first_token": t_first_token, "t_done": t_done,
+            "queued_ms": (t_admitted - t_enqueue) * 1e3,
+            "prefill_ms": (t_first_token - t_admitted) * 1e3,
+            "decode_ms": (t_done - t_first_token) * 1e3})
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -311,11 +353,28 @@ class Recorder:
         return render_prometheus(self.registry)
 
     def close(self) -> None:
-        """Flush: dump the registry as ``metric`` records onto the JSONL
-        sinks, write the exposition snapshot, close owned sinks."""
+        """Flush: write the kept spans, request records and scope maps and
+        dump the registry as ``metric`` records onto the JSONL sinks, write
+        the exposition snapshot, close owned sinks, remove the gc hook.
+        The in-memory records stay readable after the close."""
         if self._closed:
             return
         self._closed = True
+        if self._gc_hook is not None:
+            import gc
+            gc.callbacks.remove(self._gc_hook)
+            self._gc_hook = None
+        if self._sinks:
+            for sp in self.spans:
+                self._write("span", sp.step if sp.step is not None else -1,
+                            name=sp.path, ms=sp.ms, labels=dict(sp.labels),
+                            id=sp.id, parent=sp.parent,
+                            start_ns=sp.start_ns, end_ns=sp.end_ns,
+                            rid=sp.rid)
+            for r in self.requests:
+                self._write("request", -1, **r)
+            for module, scopes in self.scopes.items():
+                self._write("scopes", -1, module=module, scopes=scopes)
         if self.registry is not None and self._sinks:
             for name, type_name, children in list(self.registry.families()):
                 for labels_key, m in children:
@@ -355,9 +414,9 @@ def as_recorder(obj) -> Recorder:
 def make_recorder(telemetry_path: Optional[str] = None,
                   obs: Optional[ObsConfig] = None) -> Recorder:
     """The Recorder for one run: a JSONL sink when ``telemetry_path`` is
-    set (owned — closed with the Recorder), a metrics registry + tracer
-    when ``obs.enabled``.  Both off returns a disabled (but fresh,
-    independently closeable) Recorder."""
+    set (owned — closed with the Recorder), a metrics registry when
+    ``obs.enabled``, the tracer when ``obs.trace``.  All off returns a
+    disabled (but fresh, independently closeable) Recorder."""
     from repro.defense.telemetry import TelemetryWriter
     sinks, owned = [], []
     if telemetry_path:

@@ -10,8 +10,12 @@ through the same ``{"t", "kind", "step", ...}`` format) and prints:
   masks,
 * suspicion heat by worker (mean score, so a slowburn attacker's slow
   drift is visible even when it never crosses the ejection threshold),
-* span latency stats (count / mean / p50 / p99 per span path — exact
-  quantiles, since span records carry raw milliseconds),
+* the host time of each span path (count / mean / p50 / p99 — exact
+  quantiles, since span records carry raw milliseconds; a span times the
+  host's work and never waits for the device, so the device's time is
+  the profiler trace's to give),
+* serving request phases (queued / prefill / decode, p50 and p95 in ms)
+  from the ``request`` records,
 * q̂ trajectory and close-time counter values (``metric`` records).
 
 Pure-stdlib consumer: no jax import, so it runs on a laptop against a
@@ -96,7 +100,7 @@ def summarize(records: Sequence[dict]) -> dict:
                     sus_n[i] = sus_n.get(i, 0) + 1
     suspicion = {i: sus_sum[i] / sus_n[i] for i in sorted(sus_sum)}
 
-    # Span latency: exact quantiles from the raw per-span milliseconds.
+    # Host time per span path: exact quantiles from the raw milliseconds.
     span_ms: Dict[str, List[float]] = {}
     for rec in by_kind.get("span", []):
         ms = rec.get("ms")
@@ -109,6 +113,14 @@ def summarize(records: Sequence[dict]) -> dict:
         spans[name] = {"n": len(vals), "mean": sum(vals) / len(vals),
                        "p50": _quantile(vals, 0.50),
                        "p99": _quantile(vals, 0.99)}
+
+    phases = {}
+    for phase in ("queued", "prefill", "decode"):
+        vals = sorted(_finite(r.get(f"{phase}_ms")
+                              for r in by_kind.get("request", [])))
+        if vals:
+            phases[phase] = {"n": len(vals), "p50": _quantile(vals, 0.50),
+                             "p95": _quantile(vals, 0.95)}
 
     q_hat = _stats(_finite(r.get("q_hat") for r in train
                            if r.get("q_hat") is not None))
@@ -133,6 +145,7 @@ def summarize(records: Sequence[dict]) -> dict:
         "ejections": timeline,
         "suspicion_by_worker": suspicion,
         "spans": spans,
+        "request_phases": phases,
         "counters": counters,
         "serve_tokens": sum(produced) if produced else None,
     }
@@ -174,12 +187,19 @@ def render(summary: dict) -> str:
             out.append(f"  worker {i:>3}: {_fmt(s):>10} {bar}")
 
     if summary["spans"]:
-        out.append("span latency (ms):")
+        out.append("span host time (ms; the host's work, not the "
+                   "device's):")
         out.append(f"  {'span':<32} {'n':>6} {'mean':>10} {'p50':>10} "
                    f"{'p99':>10}")
         for name, s in summary["spans"].items():
             out.append(f"  {name:<32} {s['n']:>6} {_fmt(s['mean']):>10} "
                        f"{_fmt(s['p50']):>10} {_fmt(s['p99']):>10}")
+
+    if summary["request_phases"]:
+        out.append("request phases (ms):")
+        for phase, p in summary["request_phases"].items():
+            out.append(f"  {phase:<8} n={p['n']} p50={_fmt(p['p50'])} "
+                       f"p95={_fmt(p['p95'])}")
 
     if summary["counters"]:
         out.append("counters:")

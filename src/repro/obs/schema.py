@@ -40,8 +40,18 @@ SCHEMA: Dict[str, FrozenSet[str]] = {
                                 "active"}),
     # a point-in-time metric sample (Recorder close-time registry dump)
     "metric": frozenset({"name", "value", "labels", "type"}),
-    # one timed span (Recorder.span with tracing enabled)
-    "span": frozenset({"name", "ms", "labels"}),
+    # one closed span of the host's work (Recorder.span with tracing on;
+    # written when the Recorder closes; start_ns/end_ns on perf_counter_ns)
+    "span": frozenset({"name", "ms", "labels", "id", "parent", "start_ns",
+                       "end_ns", "rid"}),
+    # one retired serving request's phases (ServeEngine with tracing on;
+    # stamps in perf_counter seconds, phases in ms)
+    "request": frozenset({"rid", "t_enqueue", "t_admitted",
+                          "t_first_token", "t_done", "queued_ms",
+                          "prefill_ms", "decode_ms"}),
+    # the named scope of each HLO instruction of a compiled train step
+    # (topologies.SyncPS with tracing on; obs/profile.hlo_scopes)
+    "scopes": frozenset({"module", "scopes"}),
     # one deadline-quorum collection round that saw faults (repro.faults;
     # lost_round marks rounds dropped for lack of a 2-worker quorum)
     "fault": frozenset({"present", "crashed", "retries", "timeouts",

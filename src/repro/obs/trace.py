@@ -1,31 +1,44 @@
 """Span-based tracer (repro.obs, DESIGN.md §12).
 
-Spans are host-side wall-clock intervals with nesting: entering a span
-pushes its name onto a thread-local stack, so a span opened inside another
-records under the joined path (``"engine_step/decode"``), and the closed
-span lands in the Recorder's ``span_ms`` histogram (labeled by path) plus —
-when a JSONL sink is attached — as one ``kind="span"`` record.
+A span is the interval in which the *host* does one named piece of work.
+Entering a span pushes its name onto a thread-local stack, so a span opened
+inside another records under the joined path (``"engine/decode"``).  The
+closed span is kept in the Recorder's memory (``Recorder.spans``: path,
+parent, start and end in ``perf_counter_ns``, labels, step and request
+ids), lands in the ``span_ms`` histogram when metrics are on, and is written
+to the JSONL sinks when the Recorder closes.
 
-**Async-dispatch contract.**  jax dispatches asynchronously: the Python
-call that launches a jitted step returns before the device finishes, so a
-naive ``perf_counter`` pair around it times the *dispatch*, not the work.
-A span therefore exposes :meth:`Span.sync`: pass it the step's output and
-it calls ``jax.block_until_ready`` **only when tracing is enabled** —
-instrumented loops stay fully async in production (the no-op span's
-``sync`` is identity, costs one attribute lookup, allocates nothing).
+**Spans never block.**  jax dispatches asynchronously: the call that
+launches a jitted step returns before the device has run it, and a span
+around that call times the dispatch and nothing more.  That is what a span
+means here: the host's own time.  The device's time comes from the
+profiler trace: every enabled span also enters a
+``jax.profiler.TraceAnnotation`` named by its full path, with its step or
+request id as an argument, so in a trace captured through
+``obs/profile.py``'s ``--profile-dir`` window (or any ``jax.profiler``
+trace) the span sits on the same clock as the device's ops, and an idle
+gap on the device can be put down to the innermost span that covers it.
+Tracing therefore changes nothing in a loop's schedule: no span reads a
+value back from the device.
 
-When ``jax.profiler`` is importable, an enabled span also enters a
-``TraceAnnotation`` (``StepTraceAnnotation`` when ``step_num`` is given),
-so the same spans show up as named regions in a real profiler trace
-captured via ``obs/profile.py``'s ``--profile-dir`` window.
+While a Recorder traces, a ``gc.callbacks`` hook opens a ``gc`` span
+(labelled with the generation) around every collection of the cyclic
+garbage collector, so a collection's pause names itself in the trace.
+
+With tracing off, ``span()`` returns the shared ``NULL_SPAN``: nothing is
+allocated per call and no gc hook is installed.
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
 _tls = threading.local()
+# Span ids, unique in the process: a span's parent may belong to another
+# Recorder (the stack is per thread, not per Recorder).
+_ids = itertools.count(1)
 
 
 def _stack() -> list:
@@ -37,7 +50,24 @@ def _stack() -> list:
 
 def current_path() -> str:
     """The active span path ("" outside any span) — test/debug hook."""
-    return "/".join(_stack())
+    stack = _stack()
+    return stack[-1].path if stack else ""
+
+
+class SpanRecord(NamedTuple):
+    """One closed span, as the Recorder keeps it in memory."""
+    id: int
+    parent: int                 # the enclosing span's id, 0 at the top
+    path: str
+    start_ns: int               # time.perf_counter_ns()
+    end_ns: int
+    labels: Dict[str, object]
+    step: Optional[int]
+    rid: Optional[int]
+
+    @property
+    def ms(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-6
 
 
 class _NullSpan:
@@ -52,73 +82,87 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
-    @staticmethod
-    def sync(x):
-        return x
-
 
 NULL_SPAN = _NullSpan()
 
 
-def _profiler_annotation(name: str, step_num: Optional[int]):
-    """A jax.profiler annotation context for this span, or None when the
-    profiler API is unavailable (older jax, stripped builds)."""
-    try:
-        from jax import profiler
-        if step_num is not None and hasattr(profiler,
-                                            "StepTraceAnnotation"):
-            return profiler.StepTraceAnnotation(name, step_num=step_num)
-        if hasattr(profiler, "TraceAnnotation"):
-            return profiler.TraceAnnotation(name)
-    except ImportError:
-        pass
-    return None
+def _annotation(path: str, step: Optional[int], rid: Optional[int]):
+    """The profiler annotation of a span: its full path, with the step and
+    request ids as arguments (the trace keeps them as the event's
+    stats)."""
+    from jax import profiler
+    args = {}
+    if step is not None:
+        args["step"] = step
+    if rid is not None:
+        args["rid"] = rid
+    return profiler.TraceAnnotation(path, **args)
 
 
 class Span:
-    """One enabled timed span; create via ``Recorder.span(name, ...)``."""
-    __slots__ = ("_recorder", "name", "labels", "step_num", "path",
-                 "_t0", "_annotation")
+    """One enabled span; create via ``Recorder.span(name, ...)``."""
+    __slots__ = ("_recorder", "name", "labels", "step_num", "rid", "path",
+                 "id", "parent", "_t0", "_annotation", "_root")
 
     def __init__(self, recorder, name: str, labels: Dict[str, object],
-                 step_num: Optional[int] = None):
+                 step_num: Optional[int] = None, rid: Optional[int] = None,
+                 root: bool = False):
         self._recorder = recorder
         self.name = name
         self.labels = labels
         self.step_num = step_num
+        self.rid = rid
         self.path = name
-        self._t0 = 0.0
+        self.id = next(_ids)
+        self.parent = 0
+        self._t0 = 0
         self._annotation = None
+        self._root = root
 
     def __enter__(self) -> "Span":
-        stack = _stack()
-        stack.append(self.name)
-        self.path = "/".join(stack)
-        self._annotation = _profiler_annotation(self.name, self.step_num)
-        if self._annotation is not None:
-            self._annotation.__enter__()
-        self._t0 = time.perf_counter()
+        if not self._root:
+            stack = _stack()
+            if stack:
+                self.parent = stack[-1].id
+                self.path = f"{stack[-1].path}/{self.name}"
+            stack.append(self)
+        self._annotation = _annotation(self.path, self.step_num, self.rid)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
-    def sync(self, x):
-        """Block until ``x``'s device work is done (tracing is on, so the
-        span should time the computation, not the dispatch).  Returns
-        ``x`` so call sites can wrap the step expression in place."""
-        import jax
-        jax.block_until_ready(x)
-        return x
-
     def __exit__(self, *exc) -> bool:
-        ms = (time.perf_counter() - self._t0) * 1e3
-        if self._annotation is not None:
-            self._annotation.__exit__(*exc)
-            self._annotation = None
-        stack = _stack()
-        if stack and stack[-1] == self.name:
-            stack.pop()
-        self._recorder._span_done(self.path, ms, self.labels,
-                                  self.step_num)
+        t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
+        self._annotation = None
+        if not self._root:
+            stack = _stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+        self._recorder._span_done(SpanRecord(
+            self.id, self.parent, self.path, self._t0, t1, self.labels,
+            self.step_num, self.rid))
         return False
+
+
+class GcHook:
+    """The ``gc.callbacks`` hook of a tracing Recorder: a ``gc`` span
+    around each collection, at the top level whatever span is open (a
+    collection interrupts whatever allocated)."""
+
+    def __init__(self, recorder):
+        self._recorder = recorder
+        self._open: Optional[Span] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = Span(self._recorder, "gc",
+                              {"generation": info.get("generation")},
+                              root=True)
+            self._open.__enter__()
+        elif self._open is not None:
+            span, self._open = self._open, None
+            span.__exit__(None, None, None)
 
 
 # -- module-level convenience ------------------------------------------------

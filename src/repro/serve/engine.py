@@ -292,13 +292,84 @@ class ServeEngine:
     def step(self) -> int:
         """One engine iteration: retire -> admit -> prefill joiners -> one
         batched decode over every active slot.  Returns the number of
-        tokens generated this step."""
+        tokens generated this step.  While tracing, the iteration is an
+        ``engine`` span whose children split its host time: ``schedule``,
+        ``prefill`` and ``decode`` (build and dispatch), ``readback``
+        (waiting for the tokens) and ``append``."""
+        obs = self.obs
+        with obs.span("engine", step_num=self.steps_run):
+            with obs.span("schedule"):
+                admitted = self._schedule()
+            produced = 0
+
+            # Batched prefill, grouped by prompt length (one compile per
+            # (padded group size, prompt length) pair).
+            by_len: dict = {}
+            for req in admitted:
+                by_len.setdefault(req.prompt_len, []).append(req)
+            for S0, group in sorted(by_len.items()):
+                with obs.span("prefill", prompt_len=S0,
+                              batch=_pow2(len(group))):
+                    tokens = np.zeros((_pow2(len(group)), S0), np.int32)
+                    tables = np.zeros((tokens.shape[0],
+                                       self.cache.max_blocks), np.int32)
+                    for i, req in enumerate(group):
+                        tokens[i] = req.prompt
+                        tables[i] = self.cache.tables[req.slot]
+                    nxt, self.pool = self._prefill_fn(
+                        self.params, self.pool, jnp.asarray(tokens),
+                        jnp.asarray(tables))
+                with obs.span("readback"):
+                    nxt = np.asarray(nxt)
+                with obs.span("append"):
+                    for i, req in enumerate(group):
+                        self.scheduler.mark_decoding(req, nxt[i])
+                        produced += 1
+
+            # One fixed-shape decode step over all slots (inactive slots
+            # carry zero tokens/positions and all-zero table rows -> trash
+            # block).
+            decoding = [r for r in self.scheduler.active
+                        if r.state == DECODE and not r.finished]
+            if decoding:
+                k = self.decoder.k if self.decoder is not None else 1
+                with obs.span("decode", slots=len(decoding), k=k):
+                    tokens = np.zeros((self.max_slots, 1), np.int32)
+                    positions = np.zeros((self.max_slots,), np.int32)
+                    for req in decoding:
+                        tokens[req.slot, 0] = req.generated[-1]
+                        positions[req.slot] = req.decode_pos
+                    rep = (self.decoder.rep_state
+                           if self.decoder is not None else {})
+                    nxt, self.pool, new_rep, scores = self._decode_fn(
+                        self.params, self.pool, jnp.asarray(tokens),
+                        jnp.asarray(positions), self.cache.device_tables(),
+                        rep)
+                with obs.span("readback"):
+                    nxt = np.asarray(nxt)
+                with obs.span("append"):
+                    for req in decoding:
+                        self.scheduler.append_token(req, nxt[req.slot])
+                        produced += 1
+                    if self.decoder is not None:
+                        self.decoder.observe(new_rep, scores,
+                                             telemetry=obs,
+                                             step=self.steps_run)
+            obs.log("serve", self.steps_run,
+                    active=len(self.scheduler.active),
+                    queued=self.scheduler.queued, produced=produced,
+                    free_blocks=self.cache.allocator.free_blocks)
+        self.steps_run += 1
+        return produced
+
+    def _schedule(self) -> list:
+        """Expire, retire and admit; returns the admitted requests."""
         sched = self.scheduler
         obs = self.obs
         expired = sched.expire_deadlines()
         if expired:
             obs.count("serve_deadline_expired", len(expired))
-        retired = sched.retire_finished()
+        retired = self.retire_finished()
         admitted = sched.admit()
         if retired:
             obs.count("serve_retired", len(retired))
@@ -309,62 +380,17 @@ class ServeEngine:
         # would have raised OutOfBlocks mid-flight.
         if sched.queued and len(sched.active) < self.max_slots:
             obs.count("serve_outofblocks_averted")
-        produced = 0
+        return admitted
 
-        # Batched prefill, grouped by prompt length (one compile per
-        # (padded group size, prompt length) pair).
-        by_len: dict = {}
-        for req in admitted:
-            by_len.setdefault(req.prompt_len, []).append(req)
-        for S0, group in sorted(by_len.items()):
-            tokens = np.zeros((_pow2(len(group)), S0), np.int32)
-            tables = np.zeros((tokens.shape[0], self.cache.max_blocks),
-                              np.int32)
-            for i, req in enumerate(group):
-                tokens[i] = req.prompt
-                tables[i] = self.cache.tables[req.slot]
-            with obs.span("prefill", step_num=self.steps_run,
-                          prompt_len=S0, batch=tokens.shape[0]) as sp:
-                nxt, self.pool = sp.sync(self._prefill_fn(
-                    self.params, self.pool, jnp.asarray(tokens),
-                    jnp.asarray(tables)))
-            nxt = np.asarray(nxt)
-            for i, req in enumerate(group):
-                sched.mark_decoding(req, nxt[i])
-                produced += 1
-
-        # One fixed-shape decode step over all slots (inactive slots carry
-        # zero tokens/positions and all-zero table rows -> trash block).
-        decoding = [r for r in sched.active if r.state == DECODE
-                    and not r.finished]
-        if decoding:
-            tokens = np.zeros((self.max_slots, 1), np.int32)
-            positions = np.zeros((self.max_slots,), np.int32)
-            for req in decoding:
-                tokens[req.slot, 0] = req.generated[-1]
-                positions[req.slot] = req.decode_pos
-            rep = (self.decoder.rep_state if self.decoder is not None
-                   else {})
-            k = self.decoder.k if self.decoder is not None else 1
-            with obs.span("decode", step_num=self.steps_run,
-                          slots=len(decoding), k=k) as sp:
-                nxt, self.pool, new_rep, scores = sp.sync(self._decode_fn(
-                    self.params, self.pool, jnp.asarray(tokens),
-                    jnp.asarray(positions), self.cache.device_tables(),
-                    rep))
-            nxt = np.asarray(nxt)
-            for req in decoding:
-                sched.append_token(req, nxt[req.slot])
-                produced += 1
-            if self.decoder is not None:
-                self.decoder.observe(new_rep, scores,
-                                     telemetry=obs,
-                                     step=self.steps_run)
-        obs.log("serve", self.steps_run, active=len(sched.active),
-                queued=sched.queued, produced=produced,
-                free_blocks=self.cache.allocator.free_blocks)
-        self.steps_run += 1
-        return produced
+    def retire_finished(self) -> List[Request]:
+        """Retire every finished request (``Scheduler.retire_finished``)
+        and hand each one's phases to the Recorder as a request record."""
+        retired = self.scheduler.retire_finished()
+        for r in retired:
+            self.obs.request(r.rid, t_enqueue=r.t_enqueue,
+                             t_admitted=r.t_admitted,
+                             t_first_token=r.t_first_token, t_done=r.t_done)
+        return retired
 
     def run(self, max_steps: int = 100_000) -> List[Request]:
         """Drive ``step()`` until every submitted request completed."""
@@ -372,7 +398,7 @@ class ServeEngine:
             if not self.scheduler.busy:
                 break
             self.step()
-        self.scheduler.retire_finished()
+        self.retire_finished()
         return list(self.scheduler.completed)
 
     # -- measurement -----------------------------------------------------------

@@ -85,54 +85,60 @@ def make_train_step(model, *, robust_cfg: RobustConfig, opt_cfg: OptConfig,
 
     def worker_grads(params, batch):
         from repro.models import moe
-        with moe.no_data_grouping():   # worker tokens are already shard-local
+        # worker tokens are already shard-local
+        with jax.named_scope("grads"), moe.no_data_grouping():
             return jax.vmap(jax.value_and_grad(worker_loss),
                             in_axes=(None, 0))(params, batch)
 
     def aggregate(params, grads, key, active, with_scores, train_step):
-        """Robust aggregation in either layout; scores come back replicated.
-        ``train_step`` (the optimizer's step counter) reaches step-aware
-        adaptive attacks through the engine."""
-        if mesh is None:
-            return aggregate_stacked_tree(grads, robust_cfg, key,
-                                          active=active,
-                                          with_scores=with_scores,
-                                          step=train_step)
-        pspecs = tree_pspecs(params, mesh)
-        stacked_specs = jax.tree.map(
-            lambda sp: P(wa, *sp), pspecs,
-            is_leaf=lambda x: isinstance(x, P))
+        """Robust aggregation in either layout, under the ``aggregate``
+        scope; scores come back replicated.  ``train_step`` (the
+        optimizer's step counter) reaches step-aware adaptive attacks
+        through the engine."""
+        with jax.named_scope("aggregate"):
+            if mesh is None:
+                return aggregate_stacked_tree(grads, robust_cfg, key,
+                                              active=active,
+                                              with_scores=with_scores,
+                                              step=train_step)
+            pspecs = tree_pspecs(params, mesh)
+            stacked_specs = jax.tree.map(
+                lambda sp: P(wa, *sp), pspecs,
+                is_leaf=lambda x: isinstance(x, P))
 
-        out_specs = (pspecs, P()) if with_scores else pspecs
-        if active is None:
-            def agg_fn(g, k, ts):
+            out_specs = (pspecs, P()) if with_scores else pspecs
+            if active is None:
+                def agg_fn(g, k, ts):
+                    local = jax.tree.map(lambda x: x[0], g)
+                    return robust_aggregate_dist(
+                        local, robust_cfg, worker_axes=wa, model_axes=ma,
+                        key=k, with_scores=with_scores, step=ts)
+
+                return jax.shard_map(agg_fn, mesh=mesh,
+                                     in_specs=(stacked_specs, P(), P()),
+                                     out_specs=out_specs,
+                                     check_vma=False)(grads, key, train_step)
+
+            def agg_gated(g, k, act, ts):
                 local = jax.tree.map(lambda x: x[0], g)
-                return robust_aggregate_dist(local, robust_cfg,
-                                             worker_axes=wa, model_axes=ma,
-                                             key=k, with_scores=with_scores,
-                                             step=ts)
+                return robust_aggregate_dist(
+                    local, robust_cfg, worker_axes=wa, model_axes=ma,
+                    key=k, active=act, with_scores=with_scores, step=ts)
 
-            return jax.shard_map(agg_fn, mesh=mesh,
-                                 in_specs=(stacked_specs, P(), P()),
-                                 out_specs=out_specs,
-                                 check_vma=False)(grads, key, train_step)
+            return jax.shard_map(
+                agg_gated, mesh=mesh,
+                in_specs=(stacked_specs, P(), P(), P()),
+                out_specs=out_specs,
+                check_vma=False)(grads, key, active, train_step)
 
-        def agg_gated(g, k, act, ts):
-            local = jax.tree.map(lambda x: x[0], g)
-            return robust_aggregate_dist(local, robust_cfg,
-                                         worker_axes=wa, model_axes=ma,
-                                         key=k, active=act,
-                                         with_scores=with_scores, step=ts)
-
-        return jax.shard_map(agg_gated, mesh=mesh,
-                             in_specs=(stacked_specs, P(), P(), P()),
-                             out_specs=out_specs,
-                             check_vma=False)(grads, key, active, train_step)
+    def optimize(params, agg, opt_state):
+        with jax.named_scope("optimizer"):
+            return apply_updates(opt_cfg, params, agg, opt_state)
 
     def step(params, opt_state, batch, key):
         losses, grads = worker_grads(params, batch)
         agg = aggregate(params, grads, key, None, False, opt_state["step"])
-        params, opt_state = apply_updates(opt_cfg, params, agg, opt_state)
+        params, opt_state = optimize(params, agg, opt_state)
         metrics = {"loss": jnp.mean(losses),
                    "loss_per_worker": losses,
                    "grad_norm": _tree_norm(agg)}
@@ -144,23 +150,26 @@ def make_train_step(model, *, robust_cfg: RobustConfig, opt_cfg: OptConfig,
         losses, grads = worker_grads(params, batch)
         agg, scores = aggregate(params, grads, key, defense["active"], True,
                                 opt_state["step"])
-        defense = update_reputation(defense, scores, defense_cfg)
-        params, opt_state = apply_updates(opt_cfg, params, agg, opt_state)
+        with jax.named_scope("defense"):
+            defense = update_reputation(defense, scores, defense_cfg)
+        params, opt_state = optimize(params, agg, opt_state)
         metrics = {"loss": jnp.mean(losses),
                    "loss_per_worker": losses,
                    "grad_norm": _tree_norm(agg),
                    "suspicion": scores,
                    "reputation": defense["reputation"],
-                   "active": defense["active"],
-                   "q_hat": estimate_q(
-                       scores, min_gap=defense_cfg.detector_min_gap)}
+                   "active": defense["active"]}
+        with jax.named_scope("defense"):
+            metrics["q_hat"] = estimate_q(
+                scores, min_gap=defense_cfg.detector_min_gap)
         return params, opt_state, defense, metrics
 
     def compress_step(params, opt_state, batch, key, resid):
         losses, grads = worker_grads(params, batch)
-        agg, resid = aggregate_compressed_tree(
-            grads, robust_cfg, codec, resid, key, step=opt_state["step"])
-        params, opt_state = apply_updates(opt_cfg, params, agg, opt_state)
+        with jax.named_scope("aggregate"):
+            agg, resid = aggregate_compressed_tree(
+                grads, robust_cfg, codec, resid, key, step=opt_state["step"])
+        params, opt_state = optimize(params, agg, opt_state)
         metrics = {"loss": jnp.mean(losses),
                    "loss_per_worker": losses,
                    "grad_norm": _tree_norm(agg)}
@@ -170,20 +179,23 @@ def make_train_step(model, *, robust_cfg: RobustConfig, opt_cfg: OptConfig,
         from repro.defense.detector import estimate_q
         from repro.defense.reputation import update_reputation
         losses, grads = worker_grads(params, batch)
-        agg, scores, resid = aggregate_compressed_tree(
-            grads, robust_cfg, codec, resid, key,
-            active=defense["active"], with_scores=True,
-            step=opt_state["step"])
-        defense = update_reputation(defense, scores, defense_cfg)
-        params, opt_state = apply_updates(opt_cfg, params, agg, opt_state)
+        with jax.named_scope("aggregate"):
+            agg, scores, resid = aggregate_compressed_tree(
+                grads, robust_cfg, codec, resid, key,
+                active=defense["active"], with_scores=True,
+                step=opt_state["step"])
+        with jax.named_scope("defense"):
+            defense = update_reputation(defense, scores, defense_cfg)
+        params, opt_state = optimize(params, agg, opt_state)
         metrics = {"loss": jnp.mean(losses),
                    "loss_per_worker": losses,
                    "grad_norm": _tree_norm(agg),
                    "suspicion": scores,
                    "reputation": defense["reputation"],
-                   "active": defense["active"],
-                   "q_hat": estimate_q(
-                       scores, min_gap=defense_cfg.detector_min_gap)}
+                   "active": defense["active"]}
+        with jax.named_scope("defense"):
+            metrics["q_hat"] = estimate_q(
+                scores, min_gap=defense_cfg.detector_min_gap)
         return params, opt_state, defense, resid, metrics
 
     donate_argnums = (0, 1) if donate else ()

@@ -3,6 +3,7 @@ nesting under the tracer, the Recorder bus (sinks + gauge mirroring +
 lifecycle), jsonify non-finite round-trips, recorder-through-
 ``run_experiment`` integration for all four topologies, and the reporter
 CLI on a checked-in fixture JSONL."""
+import dataclasses
 import json
 import math
 import os
@@ -176,13 +177,207 @@ def test_span_stack_restored_on_exception():
     assert rec.registry.get("span_ms", name="boom").count == 1
 
 
-def test_span_sync_returns_value():
+def test_span_sync_returns_value(monkeypatch):
+    """An enabled span never waits for the device: it times the host's
+    work inside it and nothing more (the device's time comes from the
+    profiler trace)."""
+    import jax
     import jax.numpy as jnp
+
+    def refuse(*_a, **_k):
+        raise AssertionError("a span blocked on the device")
+
+    monkeypatch.setattr(jax, "block_until_ready", refuse)
+    monkeypatch.setattr(jax.Array, "block_until_ready", refuse,
+                        raising=False)
     rec = Recorder(registry=MetricsRegistry(), trace=True)
-    with rec.span("s") as sp:
-        x = sp.sync(jnp.ones((3,)))
+    with rec.span("s", step_num=4) as sp:
+        x = jnp.ones((3,)) * 2
+    assert not hasattr(sp, "sync") and not hasattr(NULL_SPAN, "sync")
     assert x.shape == (3,)
-    assert NULL_SPAN.sync("passthrough") == "passthrough"
+    done, = [r for r in rec.spans if r.path == "s"]
+    assert done.step == 4 and done.end_ns >= done.start_ns
+    rec.close()
+
+
+def test_trace_without_metrics_keeps_spans_in_memory(tmp_path):
+    """ObsConfig(enabled=False, trace=True): spans and no registry; the
+    kept spans are written to the sinks when the Recorder closes."""
+    path = str(tmp_path / "t.jsonl")
+    rec = make_recorder(path, ObsConfig(enabled=False, trace=True))
+    assert rec.registry is None and rec.trace_enabled
+    with rec.span("engine", step_num=7):
+        with rec.span("readback"):
+            pass
+    inner, outer = rec.spans
+    assert (outer.path, inner.path) == ("engine", "engine/readback")
+    assert inner.parent == outer.id and outer.parent == 0
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    assert read_jsonl(path) == []            # nothing written before close
+    rec.close()
+    spans = [r for r in read_jsonl(path) if r["kind"] == "span"]
+    assert [r["name"] for r in spans] == ["engine/readback", "engine"]
+    assert spans[1]["step"] == 7 and spans[0]["parent"] == spans[1]["id"]
+    assert rec.spans                         # still readable after close
+
+
+def test_span_annotation_in_profiler_trace(tmp_path):
+    """An enabled span is a TraceAnnotation named by its full path, its
+    step id an argument, on the profiler's clock."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    rec = Recorder(trace=True)
+    f = jax.jit(lambda x: x + 1)
+    f(jnp.ones(4)).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("sync_ps"):
+            with rec.span("dispatch", step_num=3):
+                f(jnp.ones(4)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    rec.close()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {e.name: dict(e.stats)
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("sync_ps")}
+    assert found["sync_ps/dispatch"] == {"step": 3}
+    assert "sync_ps" in found and "dispatch" not in found
+
+
+def test_gc_span_wraps_collection():
+    import gc
+    before = list(gc.callbacks)
+    off = Recorder(registry=MetricsRegistry())
+    assert gc.callbacks == before            # no tracer, no hook
+    rec = Recorder(trace=True)
+    with rec.span("outer"):
+        gc.collect()
+    gcs = [s for s in rec.spans if s.path == "gc"]
+    assert gcs and gcs[-1].labels == {"generation": 2}
+    assert gcs[-1].parent == 0               # top level, whatever is open
+    outer, = [s for s in rec.spans if s.path == "outer"]
+    assert outer.start_ns <= gcs[-1].start_ns <= gcs[-1].end_ns \
+        <= outer.end_ns
+    rec.close()
+    off.close()
+    assert gc.callbacks == before
+    n = len(rec.spans)
+    gc.collect()
+    assert len(rec.spans) == n               # the hook left with close()
+
+
+def test_gc_hook_safe_before_jax_is_imported():
+    """A tracing Recorder imports the profiler before it installs the gc
+    hook: a collection can start in the middle of jax's own import, and a
+    hook that imported jax there would find it half-initialised."""
+    import subprocess
+    import sys
+    code = ("import gc, sys\n"
+            "from repro.obs import Recorder\n"
+            "assert 'jax' not in sys.modules\n"
+            "rec = Recorder(trace=True)\n"
+            "assert 'jax.profiler' in sys.modules\n"
+            "gc.collect()\n"
+            "rec.close()\n"
+            "assert [s.path for s in rec.spans] == ['gc']\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert "Exception ignored" not in out.stderr, out.stderr
+
+
+def test_traced_sync_ps_reads_nothing_back(monkeypatch, tmp_path):
+    """A traced sync_ps run (tracing on, metrics off) calls
+    block_until_ready and reads no array back after its first step, and
+    keeps its spans and the step's scope map on its Recorder."""
+    import sys
+
+    import jax
+    from jax._src.array import ArrayImpl
+
+    from repro.experiment import resolve
+    from repro.experiment.topology import make_topology
+    calls = {"sync": 0, "read": 0}
+    real_sync, real_value = jax.block_until_ready, ArrayImpl._value
+
+    def counting_sync(x):
+        calls["sync"] += 1
+        return real_sync(x)
+
+    def counting_value(self):
+        calls["read"] += 1
+        return real_value.fget(self)
+
+    monkeypatch.setattr(jax, "block_until_ready", counting_sync)
+    monkeypatch.setattr(ArrayImpl, "_value", property(counting_value))
+    spec = dataclasses.replace(_train_spec("sync_ps", tmp_path), steps=6,
+                               log_every=1000, telemetry_path=None)
+    plan = resolve(spec, obs=ObsConfig(enabled=False, trace=True))
+    seen, loop = {}, {}
+    inner = plan.batch_fn
+
+    def batch_fn(step):
+        seen[step] = dict(calls)
+        loop.update(sys._getframe(1).f_locals)
+        return inner(step)
+
+    make_topology("sync_ps").run(dataclasses.replace(plan,
+                                                     batch_fn=batch_fn))
+    assert seen[5] == seen[1], seen           # steps 1..4: no read-back
+    rec = loop["rec"]
+    assert {"sync_ps/input", "sync_ps/dispatch", "sync_ps/record"} <= {
+        s.path for s in rec.spans}
+    (module, scopes), = rec.scopes.items()
+    assert module == "jit_defense_step"
+    assert {"grads", "aggregate/stack", "aggregate/attack",
+            "aggregate/rule", "defense", "optimizer"} <= set(scopes.values())
+
+
+def test_named_scopes_touch_only_metadata(monkeypatch, tmp_path):
+    """The train step compiles to the same program with and without its
+    named scopes: they change the HLO metadata alone."""
+    import contextlib
+    import re
+
+    import jax
+
+    from repro.data.pipeline import make_worker_batches
+    from repro.defense.reputation import init_reputation
+    from repro.experiment import resolve
+    from repro.optim import init_opt_state
+    from repro.train.step import make_train_step
+    plan = resolve(_train_spec("sync_ps", tmp_path))
+    params = plan.model.init(jax.random.PRNGKey(0))
+    args = (params, init_opt_state(plan.opt_cfg, params),
+            make_worker_batches(plan.batch_fn(0), plan.num_workers),
+            jax.random.PRNGKey(1), init_reputation(plan.num_workers))
+
+    def program():
+        fn = make_train_step(plan.model, robust_cfg=plan.robust_cfg,
+                             opt_cfg=plan.opt_cfg,
+                             num_workers=plan.num_workers, donate=False,
+                             defense_cfg=plan.defense_cfg)
+        text = fn.lower(*args).compile().as_text()
+        # the instructions, without their metadata (the stack-frame
+        # tables before them hold source locations)
+        body = text[text.index("\n%"):]
+        return re.sub(r",? ?metadata=\{[^}]*\}", "", body), text
+
+    scoped, scoped_text = program()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain, plain_text = program()
+    assert "aggregate/attack" in scoped_text
+    assert "aggregate/attack" not in plain_text
+    assert scoped == plain
 
 
 def test_disabled_recorder_spans_allocate_nothing():
@@ -361,8 +556,11 @@ def test_recorder_through_run_experiment_serve(tmp_path):
 
     fams = parse_exposition(open(snap).read())
     names = {s[1].get("name") for s in fams["repro_span_ms"]["samples"]}
-    assert {"prefill", "decode"} <= names
+    assert {"engine", "engine/schedule", "engine/prefill", "engine/decode",
+            "engine/readback", "engine/append"} <= names
     assert "repro_serve_admitted" in fams
+    reqs = [r for r in records if r["kind"] == "request"]
+    assert sorted(r["rid"] for r in reqs) == [0, 1]
 
 
 def test_run_experiment_without_obs_stays_dark(tmp_path):
@@ -387,7 +585,7 @@ def test_reporter_cli_on_fixture(capsys):
     # ejection timeline reconstructed from active-mask transitions
     assert "worker 2 ejected (train)" in out
     assert "worker 2 ejected (robust_decode)" in out
-    assert "train_step" in out                   # span latency table
+    assert "train_step" in out                   # span host-time table
     assert "ejections{stream=train} = 2" in out  # close-time counter dump
     assert "suspicion heat" in out
 

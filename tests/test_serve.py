@@ -331,3 +331,33 @@ def test_replica_telemetry_stream(model_and_params, tmp_path):
     scored = [r for r in records if r["kind"] == "robust_decode"]
     assert scored and len(scored[0]["scores"]) == 3
     assert scored[-1]["scores"][0] > max(scored[-1]["scores"][1:])
+
+
+def test_request_records_hold_queue_wait(model_and_params):
+    """With tracing on, each retired request leaves one record of its
+    phases, read from the scheduler's stamps: a request that waited for
+    the one slot shows that wait as its queued phase."""
+    from repro.obs import ObsConfig, make_recorder
+    model, params = model_and_params
+    with make_recorder(None, ObsConfig(enabled=False, trace=True)) as rec:
+        engine = ServeEngine(model, params, max_slots=1, max_seq_len=16,
+                             block_tokens=4, telemetry=rec)
+        first = engine.submit([1, 2, 3], 4)
+        queued = engine.submit([4, 5, 6], 4)
+        engine.run()
+    by_rid = {r["rid"]: r for r in rec.requests}
+    assert sorted(by_rid) == [first.rid, queued.rid]
+    q = by_rid[queued.rid]
+    assert q["queued_ms"] == pytest.approx(
+        (queued.t_admitted - queued.t_enqueue) * 1e3)
+    assert queued.t_admitted >= first.t_done    # it waited for the slot
+    assert q["queued_ms"] > by_rid[first.rid]["queued_ms"]
+    assert q["prefill_ms"] == pytest.approx(
+        (queued.t_first_token - queued.t_admitted) * 1e3)
+    assert q["decode_ms"] == pytest.approx(
+        (queued.t_done - queued.t_first_token) * 1e3)
+    steps = [s for s in rec.spans if s.path == "engine"]
+    assert len(steps) == engine.steps_run
+    assert {s.path for s in rec.spans} >= {
+        "engine/schedule", "engine/prefill", "engine/decode",
+        "engine/readback", "engine/append"}
