@@ -4,15 +4,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# Lane-axis tile: multiple of 128 (TPU lane width).  With m <= 64 workers on
-# the sublane axis, an (m, 2048) f32 block is m*8KB <= 512KB — comfortably
-# inside the ~16MB VMEM budget even with double buffering.
+# Lane-axis tile unit: a multiple of 128 (TPU lane width).  The trmean and
+# krum kernels take (m, 2048) blocks; the phocas kernels take a multiple of
+# it from ``lane_tile``.
 DEFAULT_TILE_D = 2048
 
 # Sublane (second-minor) axis of the f32 TPU vector-memory tile: min tile is
 # (8, 128).  Layout constants that put a token/worker axis on the sublane
 # dimension (e.g. serve/cache.DEFAULT_BLOCK_TOKENS) must be multiples of it.
 SUBLANE = 8
+
+# ``lane_tile``'s bounds.  On a v5e each grid step costs ~0.15 µs besides
+# its block's work, and the phocas kernels' time stops falling past 4,096
+# lanes (at m = 4 and 20): wider blocks run the body slower than their
+# fewer steps save.  A tile may fill at most 12 MiB of the 16 MiB of scoped
+# VMEM the TPU compiler gives a Pallas kernel by default.
+LANE_TILE_MAX = 2 * DEFAULT_TILE_D
+LANE_TILE_VMEM_BYTES = 12 * 2**20
 
 # On CPU containers Pallas runs the kernel body in interpret mode.
 INTERPRET = jax.default_backend() == "cpu"
@@ -71,3 +79,19 @@ def pad_lanes(u: jax.Array, tile: int):
     if pad:
         u = jnp.pad(u, ((0, 0), (0, pad)))
     return u, d
+
+
+def lane_tile(m: int, d: int) -> int:
+    """Lane tile for an (m, d) float32 worker matrix: the widest multiple of
+    ``DEFAULT_TILE_D`` up to ``LANE_TILE_MAX`` whose blocks fit
+    ``LANE_TILE_VMEM_BYTES`` (at least one unit), capped at d rounded up
+    to 128 lanes."""
+    rows = -(-m // SUBLANE) * SUBLANE
+    # Float32 words per lane: the double-buffered (m, tile) input block, the
+    # double-buffered (1, tile) output row, and 16 (m, tile) temporaries of
+    # the body (the v5e compiler keeps at most 13 live, in the phocas
+    # sorting network; 4.1 in the extraction body).
+    words = 2 * rows + 2 + 16 * rows
+    fit = min(LANE_TILE_VMEM_BYTES // (4 * words), LANE_TILE_MAX)
+    return min(max(fit // DEFAULT_TILE_D, 1) * DEFAULT_TILE_D,
+               -(-d // 128) * 128)

@@ -1,10 +1,18 @@
 """Pallas TPU kernels: fused Phocas aggregation.
 
-Single VMEM pass per (m, TILE_D) block: computes the b-trimmed mean (as in
+Single VMEM pass per (m, tile) block: computes the b-trimmed mean (as in
 the trmean kernel), then drops the b values farthest from it and averages
 the remaining m-b — the trimmed mean never round-trips to HBM, which is the
 fusion win over running trmean + a second distance/selection pass (2 fewer
 HBM reads of the m×d matrix).
+
+The lane tile comes from the shape (``kernels.common.lane_tile``): 4,096
+lanes where the VMEM budget allows, which halves the grid steps of 2,048
+while the body keeps its pace (wider blocks slow the body more than their
+fewer steps save; DESIGN.md §8).  The grid is ragged, ``cdiv(d, tile)``
+steps with no pad: the last block's lanes past d compute garbage that
+Pallas never writes back, and the counts mask them.  Columns are
+independent, so the results do not depend on the tile.
 
 Two variants share the public entry points (DESIGN.md §8):
 
@@ -18,8 +26,8 @@ Two variants share the public entry points (DESIGN.md §8):
   cheap window ops.
 
 The ``*_counts`` kernel additionally emits per-worker drop counts (the
-defense suspicion statistic) as a second per-grid-block output, with padded
-lanes masked out.
+defense suspicion statistic) as a second per-grid-block output, with lanes
+past d masked out.
 """
 from __future__ import annotations
 
@@ -31,14 +39,14 @@ from jax.experimental import pallas as pl
 
 from repro.core.selection import (nearest_window_sum, sorted_rows,
                                   stable_ranks, trimmed_mean_of_sorted)
-from repro.kernels.common import (DEFAULT_TILE_D, INTERPRET, extract_max,
-                                  extract_min, masked_sum, pad_lanes)
+from repro.kernels.common import (INTERPRET, extract_max, extract_min,
+                                  lane_tile, masked_sum)
 from repro.kernels.trmean.kernel import (COUNTS_LANES, _counts_row,
                                          _lane_mask, _rows_of, use_network)
 
 
 def _trimmed_center(u, *, b: int, m: int):
-    """Trimmed-mean center of an (m, TILE_D) block."""
+    """Trimmed-mean center of an (m, tile) block."""
     valid = jnp.ones(u.shape, jnp.bool_)
     for _ in range(b):
         valid = extract_min(u, valid)
@@ -48,7 +56,7 @@ def _trimmed_center(u, *, b: int, m: int):
 
 
 def _drop_farthest(u, center, *, b: int):
-    """(m, TILE_D) mask of the b values farthest from ``center``.
+    """(m, tile) mask of the b values farthest from ``center``.
 
     Ties break on the HIGHEST worker index, matching the stable-argsort
     oracle (which ranks lower indices as "nearer" on equal distance).
@@ -66,7 +74,7 @@ def _drop_farthest(u, center, *, b: int):
 
 
 def _phocas_kernel(u_ref, o_ref, *, b: int, m: int):
-    u = u_ref[...].astype(jnp.float32)              # (m, TILE_D)
+    u = u_ref[...].astype(jnp.float32)              # (m, tile)
     dropped = _drop_farthest(u, _trimmed_center(u, b=b, m=m), b=b)
     o_ref[...] = (masked_sum(u, ~dropped) / (m - b))[None]
 
@@ -97,54 +105,61 @@ def _phocas_counts_kernel(u_ref, o_ref, c_ref, *, b: int, m: int, d: int,
     c_ref[...] = _counts_row(dropped, lane_ok, m)
 
 
-@functools.partial(jax.jit, static_argnames=("b", "tile_d", "interpret"))
-def phocas_pallas(u: jax.Array, b: int, *, tile_d: int = DEFAULT_TILE_D,
-                  interpret: bool = INTERPRET) -> jax.Array:
-    """(m, d) f32 -> (d,) Phocas aggregation via pallas_call."""
-    m = u.shape[0]
+def _check_b(m: int, b: int) -> None:
     if not 0 <= b <= (m + 1) // 2 - 1:
         raise ValueError(f"b={b} out of range for m={m}")
-    u = u.astype(jnp.float32)
-    u, d = pad_lanes(u, tile_d)
-    dp = u.shape[1]
+
+
+@functools.partial(jax.jit, static_argnames=("b", "tile_d", "interpret"))
+def phocas_pallas(u: jax.Array, b: int, *, tile_d: int | None = None,
+                  interpret: bool = INTERPRET) -> jax.Array:
+    """(m, d) f32 -> (d,) Phocas aggregation via pallas_call.
+
+    ``tile_d=None`` takes the lane tile from the shape (``lane_tile``).
+    """
+    m, d = u.shape
+    _check_b(m, b)
+    tile = lane_tile(m, d) if tile_d is None else tile_d
     body = _phocas_kernel_net if use_network(m, 3 * b) else _phocas_kernel
     out = pl.pallas_call(
         functools.partial(body, b=b, m=m),
-        grid=(dp // tile_d,),
-        in_specs=[pl.BlockSpec((m, tile_d), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, tile_d), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, dp), jnp.float32),
+        grid=(pl.cdiv(d, tile),),
+        in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
-    )(u)
-    return out[0, :d]
+    )(u.astype(jnp.float32))
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("b", "tile_d", "interpret"))
-def phocas_counts_pallas(u: jax.Array, b: int, *,
-                         tile_d: int = DEFAULT_TILE_D,
+def phocas_counts_pallas(u: jax.Array, b: int, *, tile_d: int | None = None,
                          interpret: bool = INTERPRET):
-    """(m, d) f32 -> ((d,) Phocas aggregate, (m,) per-worker drop counts)."""
-    m = u.shape[0]
-    if not 0 <= b <= (m + 1) // 2 - 1:
-        raise ValueError(f"b={b} out of range for m={m}")
+    """(m, d) f32 -> ((d,) Phocas aggregate, (m,) per-worker drop counts).
+
+    ``tile_d=None`` takes the lane tile from the shape (``lane_tile``).
+    """
+    m, d = u.shape
+    _check_b(m, b)
     if m > COUNTS_LANES:
         raise ValueError(f"counts kernel packs m into {COUNTS_LANES} lanes; "
                          f"got m={m}")
-    u = u.astype(jnp.float32)
-    u, d = pad_lanes(u, tile_d)
-    dp = u.shape[1]
-    nblocks = dp // tile_d
+    tile = lane_tile(m, d) if tile_d is None else tile_d
+    nblocks = pl.cdiv(d, tile)
     agg, counts = pl.pallas_call(
         functools.partial(_phocas_counts_kernel, b=b, m=m, d=d,
-                          tile_d=tile_d, network=use_network(m, 3 * b)),
+                          tile_d=tile, network=use_network(m, 3 * b)),
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec((m, tile_d), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((1, tile_d), lambda i: (0, i)),
+        in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((1, tile), lambda i: (0, i)),
                    pl.BlockSpec((None, 1, COUNTS_LANES),
                                 lambda i: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((1, dp), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct((1, d), jnp.float32),
                    jax.ShapeDtypeStruct((nblocks, 1, COUNTS_LANES),
                                         jnp.float32)],
         interpret=interpret,
-    )(u)
-    return agg[0, :d], jnp.sum(counts, axis=(0, 1))[:m]
+    )(u.astype(jnp.float32))
+    # Each block's count is an exact integer; summing them as integers keeps
+    # the total exact past 2**24 and the same whatever the tile.
+    counts = jnp.sum(counts[:, 0, :m].astype(jnp.int32), axis=0)
+    return agg[0], counts.astype(jnp.float32)

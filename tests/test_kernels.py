@@ -1,13 +1,18 @@
 """Pallas kernel validation: shape/dtype sweeps + hypothesis properties
 against the pure-jnp oracles (interpret mode on CPU)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.experimental import pallas as pl
 
 from repro.kernels import ops as kops
+from repro.kernels.common import DEFAULT_TILE_D, LANE_TILE_MAX, lane_tile
 from repro.kernels.krum.ref import pairwise_sq_dists_ref
+from repro.kernels.phocas.kernel import phocas_counts_pallas, phocas_pallas
 from repro.kernels.phocas.ref import phocas_ref
 from repro.kernels.trmean.ref import trmean_ref
 
@@ -46,12 +51,84 @@ def test_trmean_kernel_sweep(m, d):
                                    atol=1e-4, err_msg=f"b={b}")
 
 
-@pytest.mark.parametrize("m", [4, 5, 20, 32])
-@pytest.mark.parametrize("d", [1, 100, 2048, 5000])
+def _ragged_width(m):
+    """Three whole lane tiles of the shape-chosen width and one lane more,
+    so the grid's last block holds a single real column."""
+    return 3 * lane_tile(m, 2**31) + 1
+
+
+@pytest.mark.parametrize("m", [3, 4, 5, 8, 20, 32])
+@pytest.mark.parametrize("d", [1, 100, 2048, 3001, 5000, "ragged"])
 def test_phocas_kernel_sweep(m, d):
+    d = _ragged_width(m) if d == "ragged" else d
     u = 10 * jax.random.normal(jax.random.fold_in(KEY, m + d), (m, d))
     for b in _valid_bs(m):
         _assert_phocas_close(u, b, kops.phocas(u, b), phocas_ref(u, b))
+
+
+@pytest.mark.parametrize("m,d", [(3, 3001), (4, "ragged"), (5, 3001),
+                                 (8, "ragged"), (20, 3001)])
+def test_phocas_shape_tile_matches_default_tile(m, d):
+    """The lane tile chosen from the shape changes only the blocks: columns
+    are independent, so aggregate and counts equal the 2,048-lane grid's
+    bit for bit, extraction and network bodies alike."""
+    d = _ragged_width(m) if d == "ragged" else d
+    u = 10 * jax.random.normal(jax.random.fold_in(KEY, 7 * m + d), (m, d))
+    u = u.at[0].set(200.0 * u[0])
+    for b in _valid_bs(m):
+        np.testing.assert_array_equal(
+            phocas_pallas(u, b), phocas_pallas(u, b, tile_d=DEFAULT_TILE_D))
+        for got, want in zip(phocas_counts_pallas(u, b),
+                             phocas_counts_pallas(u, b,
+                                                  tile_d=DEFAULT_TILE_D)):
+            np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("m,b", [(4, 1), (5, 2), (8, 3)])
+def test_phocas_counts_ignore_lanes_past_d(m, b):
+    """On the chip the ragged grid's last block holds stale values past d
+    (interpret mode reads NaN there, which no body counts); the counts
+    body must mask them whatever they hold.  Feed it a block padded with an
+    attack-sized row and check the counts against the unpadded matrix."""
+    from repro.core.aggregators import phocas_stats
+    from repro.kernels.phocas.kernel import _phocas_counts_kernel
+    from repro.kernels.trmean.kernel import COUNTS_LANES, use_network
+    d, tile = 3001, DEFAULT_TILE_D
+    u = jax.random.normal(jax.random.fold_in(KEY, m), (m, d))
+    stale = jnp.zeros((m, (-d) % tile)).at[m - 1].set(1e6)
+    nblocks = pl.cdiv(d, tile)
+    _, counts = pl.pallas_call(
+        functools.partial(_phocas_counts_kernel, b=b, m=m, d=d, tile_d=tile,
+                          network=use_network(m, 3 * b)),
+        grid=(nblocks,),
+        in_specs=[pl.BlockSpec((m, tile), lambda i: (0, i))],
+        out_specs=[pl.BlockSpec((1, tile), lambda i: (0, i)),
+                   pl.BlockSpec((None, 1, COUNTS_LANES),
+                                lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((1, nblocks * tile), jnp.float32),
+                   jax.ShapeDtypeStruct((nblocks, 1, COUNTS_LANES),
+                                        jnp.float32)],
+        interpret=True,
+    )(jnp.concatenate([u, stale], axis=1))
+    np.testing.assert_array_equal(counts.sum(axis=(0, 1))[:m],
+                                  phocas_stats(u, b)[1])
+
+
+@pytest.mark.parametrize("d", [1, 100, 3001, 786_432, 268_447_744])
+def test_lane_tile(d):
+    """Lane-aligned, no wider than d needs or than ``LANE_TILE_MAX``, and
+    never wider for more workers; the widest tile at the cells' m, one unit
+    where a block of 128 workers would outgrow the VMEM budget."""
+    cap = -(-d // 128) * 128
+    prev = None
+    for m in (1, 3, 4, 5, 8, 9, 20, 32, 64, 128):
+        tile = lane_tile(m, d)
+        assert tile % 128 == 0 and tile <= min(cap, LANE_TILE_MAX)
+        assert tile % DEFAULT_TILE_D == 0 or tile == cap
+        assert prev is None or tile <= prev
+        prev = tile
+    assert lane_tile(4, d) == min(cap, LANE_TILE_MAX)
+    assert lane_tile(128, d) == min(cap, DEFAULT_TILE_D)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.float16])

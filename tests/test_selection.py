@@ -273,15 +273,20 @@ def test_trmean_counts_kernel_matches_xla(m, b):
     np.testing.assert_allclose(np.asarray(kc), np.asarray(xc), atol=0)
 
 
-@pytest.mark.parametrize("m,b", [(8, 2), (8, 3), (20, 2), (20, 9), (5, 2)])
+@pytest.mark.parametrize("m,b", [(8, 2), (8, 3), (20, 2), (20, 9), (5, 2),
+                                 (3, 1), (4, 1)])
 def test_phocas_counts_kernel_matches_xla(m, b):
+    """At widths whose last lane tile is partial (the shape-chosen tile and
+    the 2,048-lane one): lanes past d never count."""
     from repro.core.aggregators import phocas_stats
+    from repro.kernels.common import lane_tile
     from repro.kernels.phocas.ops import phocas_with_counts
-    u = _u(9, m=m, d=3001)
-    ka, kc = phocas_with_counts(u, b)
-    xa, xc, _ = phocas_stats(u, b)
-    np.testing.assert_allclose(np.asarray(ka), np.asarray(xa), atol=1e-4)
-    np.testing.assert_allclose(np.asarray(kc), np.asarray(xc), atol=0)
+    for d in (3001, 3 * lane_tile(m, 2**31) + 1):
+        u = _u(9, m=m, d=d)
+        ka, kc = phocas_with_counts(u, b)
+        xa, xc, _ = phocas_stats(u, b)
+        np.testing.assert_allclose(np.asarray(ka), np.asarray(xa), atol=1e-4)
+        np.testing.assert_allclose(np.asarray(kc), np.asarray(xc), atol=0)
 
 
 def test_kernel_network_variant_heuristic():

@@ -7,6 +7,8 @@ kernel may use, programs that overflow HBM.  The topology is described
 inside a fixture (never at import), so every test worker collects the same
 tests and only the worker that runs this file loads the TPU compiler.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -57,6 +59,30 @@ def _compiled_text(fn, *args, **kw) -> str:
 def test_trim_kernels_compile(one_chip, kernel, m):
     u = jax.ShapeDtypeStruct((m, MLP_D), jnp.float32, sharding=one_chip)
     assert "tpu_custom_call" in _compiled_text(kernel, u, 1)
+
+
+# The benchmark cells' shapes: the train cell's (m=4, 268,447,744-parameter)
+# worker matrix and the serve cell's k=3 replicas' (3, 786,432) logits.
+CELL_SHAPES = [(4, 268_447_744), (3, 786_432)]
+
+
+@pytest.mark.parametrize("shape", CELL_SHAPES, ids=lambda s: "x".join(
+    map(str, s)))
+@pytest.mark.parametrize("kernel", [phocas_pallas, phocas_counts_pallas],
+                         ids=lambda f: f.__name__)
+def test_phocas_kernels_read_the_matrix_in_place(one_chip, kernel, shape):
+    """At the cells' shapes the kernel is one custom call named as the
+    trace reads it (``bench/metrics/agg_kernel_ms.py``), fed straight from
+    the parameter: no pad, and no float32 (m, d) copy ahead of it."""
+    m, d = shape
+    u = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _compiled_text(kernel, u, 1)
+    name = kernel.__name__
+    call = re.search(rf"^\s*%{name}(\.\d+)? = .* custom-call\(%u[.\d]*\)"
+                     r'.*custom_call_target="tpu_custom_call"', text, re.M)
+    assert call, f"no tpu_custom_call named {name} on the parameter"
+    assert not re.search(r"\bpad\(", text)
+    assert not re.search(rf"= f32\[{m},{d}\]\S* copy\(", text)
 
 
 def test_krum_gram_kernel_compiles(one_chip):
